@@ -585,6 +585,8 @@ def run(out_path: Optional[str] = None) -> dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default=None,
                     help="write the BENCH_kernels.json artifact here "

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import emit, note, smoke, time_fn
 
 N = smoke(500_000, 20_000_000)
@@ -45,4 +47,5 @@ def run(n: int = N) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

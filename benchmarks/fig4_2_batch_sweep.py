@@ -16,6 +16,7 @@ from repro.core import dominance as dm
 from repro.core.lattice import init_grid
 from repro.core.rng import proposal_batch
 from repro.core import batched
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import emit, note, smoke, time_fn
 
@@ -60,4 +61,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

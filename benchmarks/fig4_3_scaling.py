@@ -27,6 +27,7 @@ if os.environ.get("ESCG_FAKE_DEVICES"):
 import jax
 
 from repro.core import EscgParams, dominance as dm, engines
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import emit, note, smoke, time_fn
 
@@ -95,4 +96,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

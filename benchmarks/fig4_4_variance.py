@@ -17,6 +17,7 @@ import numpy as np
 from repro.core import EscgParams, dominance as dm
 from repro.core.lattice import init_grid
 from repro.core.simulation import build_chunk_fn
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import emit, note, smoke
 
@@ -49,4 +50,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

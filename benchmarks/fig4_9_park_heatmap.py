@@ -13,6 +13,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core.park import survival_probabilities
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import emit, note, smoke, time_fn
 
@@ -38,4 +39,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -11,6 +11,7 @@ import os
 from repro.configs import ARCHS, SHAPES
 from repro.models.registry import build_model
 from repro.parallel import roofline
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import emit, note
 
@@ -71,4 +72,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

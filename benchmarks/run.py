@@ -10,8 +10,11 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     # bench_gate is intentionally absent: it is the perf GATE, not a
     # figure — the CI perf-smoke job runs it standalone (with --out) and
     # would otherwise pay its engine-build sweep twice per run

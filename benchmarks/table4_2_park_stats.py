@@ -14,6 +14,7 @@ from __future__ import annotations
 import time
 
 from repro.core.park import species5_extinction_std
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import emit, note, smoke
 
@@ -34,4 +35,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -27,6 +27,7 @@ import jax
 
 from repro.core.scenarios import EngineConfig, RunConfig, make_scenario
 from repro.core.trials import run_trials
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import emit, note, smoke, time_fn
 
@@ -92,4 +93,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

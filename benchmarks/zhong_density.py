@@ -20,6 +20,7 @@ import numpy as np
 from repro.core import dominance as dm
 from repro.core.scenarios import EngineConfig, RunConfig, make_scenario
 from repro.core.trials import run_trials
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import emit, note, smoke
 
@@ -48,4 +49,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

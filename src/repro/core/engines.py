@@ -235,6 +235,17 @@ def validate_params(p: "EscgParams") -> None:
                 f"{p.engine!r} (got {p.local_kernel!r}): only the "
                 "in-kernel Philox schedule can thread K MCS through one "
                 "launch")
+        # the megakernel holds each device's whole block in VMEM; where the
+        # block is the whole lattice, refuse here what Mosaic would refuse
+        # (kernels/escg_update_fused.py checks every block at trace time)
+        whole = (p.engine == "pallas_fused"
+                 or (p.engine == "sharded" and p.shard_grid == (1, 1))
+                 or (p.engine == "sharded_pod"
+                     and (p.mesh_shape is None
+                          or tuple(p.mesh_shape[1:]) == (1, 1))))
+        if whole:
+            from ..kernels.escg_update_fused import check_mega_fits  # lazy
+            check_mega_fits(p.height, p.length, p.cell_dtype)
     if p.obs_capacity < 0:
         raise ValueError(f"obs_capacity must be >= 0, got {p.obs_capacity}")
     if p.observables:

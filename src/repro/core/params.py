@@ -16,6 +16,7 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 from .engines import engine_names, validate_params as _validate_engine
+from .rules import quantize_rate
 
 
 def __getattr__(name: str):
@@ -106,12 +107,14 @@ class EscgParams:
         u <  t_eps          -> migration
         u <  t_eps_mu       -> interaction
         else                -> reproduction
-        (paper Algorithm 3.2 ordering)
+        (paper Algorithm 3.2 ordering), as the float32 values the device
+        compares (``rules.quantize_rate``)
         """
         total = self.mu + self.sigma + self.eps
         if total <= 0:
             raise ValueError("mu + sigma + epsilon must be positive")
-        return self.eps / total, (self.eps + self.mu) / total
+        return (quantize_rate(self.eps / total),
+                quantize_rate((self.eps + self.mu) / total))
 
     @property
     def proposals_per_round(self) -> int:

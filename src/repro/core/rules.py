@@ -28,6 +28,17 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+_F32_TINY = float(np.finfo(np.float32).tiny)
+
+
+def quantize_rate(x: float) -> float:
+    """``x`` as the device holds it: rounded to float32, with subnormals
+    flushed to zero (XLA on CPU and TPU flushes them in every comparison).
+    The one place host-side rates and thresholds become device numbers."""
+    x = float(np.float32(x))
+    return 0.0 if abs(x) < _F32_TINY else x
 
 
 def apply_pair(s: jax.Array, n: jax.Array, u_act: jax.Array,
@@ -67,17 +78,21 @@ def apply_pair(s: jax.Array, n: jax.Array, u_act: jax.Array,
 
 def apply_pair_reference(s: int, n: int, u_act: float, u_dom: float,
                          t_eps: float, t_eps_mu: float, dom) -> Tuple[int, int]:
-    """Plain-Python transliteration of paper Algorithm 3.2 (test oracle)."""
+    """Plain-Python transliteration of paper Algorithm 3.2 (test oracle).
+    Every real number is first made the float32 the device compares
+    (``quantize_rate``)."""
+    u_act, u_dom, t_eps, t_eps_mu = (quantize_rate(v) for v in
+                                     (u_act, u_dom, t_eps, t_eps_mu))
     if s == n:
         return s, n
     if u_act < t_eps:                       # migration
         return n, s
     if u_act < t_eps_mu:                    # interaction
-        p1 = float(dom[s, n])
-        p2 = float(dom[n, s])
+        p1 = quantize_rate(dom[s, n])
+        p2 = quantize_rate(dom[n, s])
         if u_dom < p1:
             return s, 0                     # neighbour dies
-        if u_dom < p1 + p2:
+        if u_dom < quantize_rate(p1 + p2):
             return 0, n                     # self dies
         return s, n
     # reproduction

@@ -10,9 +10,9 @@ round, entirely inside a single ``shard_map`` region:
      neighbouring device and dynamic-slices the shifted window out of the
      extended block. O(halo x perimeter) bytes per round, never a
      whole-lattice gather. (A global ``jnp.roll`` on the shard_map output
-     miscompiles under jit on jax 0.4.x — values get summed across the
-     device axis — so the roll MUST stay inside the shard_map region; see
-     tests/test_sharded_engine.py.)
+     miscompiled under jit on jax 0.4.37 — values got summed across the
+     device axis; not reproduced on jax 0.9.0 — so the roll stays inside
+     the shard_map region; see tests/test_sharded_engine.py.)
   2. **local update**: every device regenerates the per-tile Philox
      proposal streams for exactly the tiles it owns
      (``rng.tile_stream_batch`` keyed by global tile id) and runs the same
@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .engines import (BuiltEngine, _tiled_setup, fused_round_inputs,
                       multi_round_inputs)
@@ -123,7 +123,7 @@ def _update_tiles(local: jax.Array, props: ProposalBatch,
         return escg_update.escg_tile_round(
             local, props.cell, props.dirn, props.u_act, props.u_dom,
             dom, jnp.asarray(DIRS, jnp.int32), tile_shape, t_eps, t_eps_mu,
-            interpret=kernel_ops._default_interpret(None))
+            interpret=kernel_ops._default_interpret())
     th, tw = tile_shape
     tiles = to_tiles(local, th, tw)
     upd = jax.vmap(lambda t, c, d, a, u: tile_update(
@@ -189,7 +189,7 @@ def make_local_round(p, dom, shard_grid: Tuple[int, int],
 
     if p.local_kernel == "fused":
         from ..kernels import escg_update_fused, ops as kernel_ops  # lazy
-        interp = kernel_ops._default_interpret(None)
+        interp = kernel_ops._default_interpret()
 
         def local_round(gl, seed, shift):
             gl = shard_shift2d(gl, shift, (th, tw), (dr, dc), row_axis,
@@ -242,7 +242,7 @@ def make_local_multi_round(p, dom, shard_grid: Tuple[int, int],
     from ..kernels import escg_update_fused, ops as kernel_ops  # lazy
     escg_update_fused.check_counter_capacity(
         (p.height // th) * (p.length // tw), k_per)
-    interp = kernel_ops._default_interpret(None)
+    interp = kernel_ops._default_interpret()
     n_counts = p.species + 1
     # trace safety: this factory runs lazily under the drivers' jitted
     # chunks (the per-k_steps shard_map cache), so jnp constants must be
@@ -302,7 +302,7 @@ def build_engine(params, dom: jax.Array,
 
     round_fn = shard_map(local_round, mesh=mesh,
                          in_specs=(grid_spec, P(), P()),
-                         out_specs=grid_spec, check_rep=False)
+                         out_specs=grid_spec, check_vma=False)
 
     def one_mcs(grid, key):
         stream, shift = round_stream_inputs(p, key, th, tw)
@@ -323,7 +323,7 @@ def build_engine(params, dom: jax.Array,
                 multi_fns[k_steps] = shard_map(
                     local_multi, mesh=mesh,
                     in_specs=(grid_spec, P(), P()),
-                    out_specs=(grid_spec, P()), check_rep=False)
+                    out_specs=(grid_spec, P()), check_vma=False)
             return multi_fns[k_steps]
 
         def multi_mcs(grid, key, k_steps):
@@ -379,7 +379,7 @@ def sharded_run_round(grid: jax.Array, props: ProposalBatch,
         local_round, mesh=mesh,
         in_specs=(grid_spec, P(), prop_spec, prop_spec, prop_spec,
                   prop_spec),
-        out_specs=grid_spec, check_rep=False)
+        out_specs=grid_spec, check_vma=False)
 
     return update(grid, shift, reshape_props(props.cell),
                   reshape_props(props.dirn), reshape_props(props.u_act),

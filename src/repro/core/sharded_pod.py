@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .engines import BuiltEngine, _tiled_setup, multi_round_inputs
 from .sharded import (build_engine as build_grid_engine, make_local_round,
@@ -91,7 +91,7 @@ def build_engine(params, dom: jax.Array,
     round_fn = shard_map(
         lambda gs, kps, shifts: jax.vmap(local_round)(gs, kps, shifts),
         mesh=mesh, in_specs=(batch_spec, pod_spec, pod_spec),
-        out_specs=batch_spec, check_rep=False)
+        out_specs=batch_spec, check_vma=False)
 
     def one_mcs_batch(grids, keys):
         """Advance every trial one MCS. ``grids``: (n, H, W) on
@@ -123,7 +123,7 @@ def build_engine(params, dom: jax.Array,
                     lambda gs, seeds, shifts:
                         jax.vmap(local_multi)(gs, seeds, shifts),
                     mesh=mesh, in_specs=(batch_spec, pod_spec, pod_spec),
-                    out_specs=(batch_spec, pod_spec), check_rep=False)
+                    out_specs=(batch_spec, pod_spec), check_vma=False)
             return multi_fns[k_steps]
 
         def multi_mcs_batch(grids, keys, k_steps):

@@ -42,6 +42,8 @@ def tile_update(tile: jax.Array, props: ProposalBatch, t_eps: float,
     th, tw = tile.shape
     iw = tw - 2
     dirs = jnp.asarray(DIRS)
+    rows = lax.broadcasted_iota(jnp.int32, (th, tw), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (th, tw), 1)
 
     def body(t, p):
         cell, dirn, ua, ud = p
@@ -52,8 +54,11 @@ def tile_update(tile: jax.Array, props: ProposalBatch, t_eps: float,
         s = t[r, c]
         n = t[nr, nc]
         ns, nn = apply_pair(s, n, ua, ud, t_eps, t_eps_mu, dom)
-        t = t.at[r, c].set(ns)
-        t = t.at[nr, nc].set(nn)
+        # masked selects, not ``t.at[r, c].set``: vmapped over the tiles of
+        # a lattice of more than ~5M cells, that scatter returns wrong
+        # cells on TPU v5e (jax/libtpu 0.9.0 / 0.0.34); a select cannot
+        t = jnp.where((rows == r) & (cols == c), ns, t)
+        t = jnp.where((rows == nr) & (cols == nc), nn, t)
         return t, None
 
     tile, _ = lax.scan(body, tile,

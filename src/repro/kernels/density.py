@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -76,4 +76,4 @@ def density_counts_sharded(grid: jax.Array, species: int, mesh: Mesh,
 
     return shard_map(local_counts, mesh=mesh,
                      in_specs=P(row_axis, col_axis), out_specs=P(),
-                     check_rep=False)(grid)
+                     check_vma=False)(grid)

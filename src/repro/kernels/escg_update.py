@@ -1,20 +1,27 @@
 """Pallas TPU kernel for the sublattice ESCG round (DESIGN.md §2, E3).
 
-One program = one (th, tw) lattice tile resident in VMEM. The program plays
-its K pre-generated proposals **sequentially** (``fori_loop`` with dynamic
-scalar load/store) — race-free by construction — while the Pallas grid runs
-all tiles in parallel across cores. This is the TPU-native replacement for
-the paper's CUDA atomics: spatial disjointness instead of per-address
-arbitration.
+One program = one (th, tw) lattice tile. It plays the tile's K
+pre-generated proposals **sequentially** (``fori_loop``) — race-free by
+construction. This is the TPU-native replacement for the paper's CUDA
+atomics: spatial disjointness instead of per-address arbitration.
 
-Layout notes (TPU target):
-  * grid tile (th, tw): tw = 128 aligns with the lane dimension; th is a
-    multiple of 8 for int32 sublane packing. Other shapes work via compiler
-    padding (and in interpret mode) but 8x128 multiples are the fast path.
+Layout (what Mosaic compiles for TPU):
+  * the grid is (row bands, tiles per band). The lattice block is the
+    whole (th, W) band: a block that spans the full width is legal for
+    any W and any th, whereas (8, 16)-style tile blocks are not. The
+    band stays resident in VMEM while the band's tiles run one after
+    another (the tile axis is ``arbitrary``: consecutive programs revisit
+    the same output block).
+  * cells are read and written as "load the row at a dynamic sublane
+    index, select one lane with a mask, store the row". Mosaic refuses a
+    single element at a dynamic lane offset.
   * proposals arrive as (T, K) int32/float32 arrays (the paper's
-    pre-generated random-number buffers, T1) and are consumed by lookup.
-  * the dominance matrix (S+1, S+1) and direction table (8, 2) are tiny and
-    replicated to every program.
+    pre-generated random-number buffers, T1); each program gets its
+    tile's (1, K) slice in SMEM, with the dominance matrix and the
+    direction table, so every address is a scalar.
+  * int8 lattices: Mosaic loads int8 only as whole blocks, so the band is
+    widened to int32 in a VMEM scratch, updated there, and narrowed back
+    once per band.
 
 Oracle: ``repro.core.sublattice.tile_update`` (pure jnp). The kernel must
 match it bit-for-bit; see tests/test_kernels.py.
@@ -22,69 +29,165 @@ match it bit-for-bit; see tests/test_kernels.py.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+SMEM = pltpu.SMEM
+
+
+# the whole (small) array in SMEM for every program
+SMEM_FULL = pl.BlockSpec(memory_space=SMEM)
+
+
+def read_cell(ref, r, c):
+    """Scalar int32 value of ``ref[r, c]`` (dynamic r and c)."""
+    row = ref[pl.ds(r, 1), :]
+    lanes = lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lanes == c, row, 0))
+
+
+def write_cell(ref, r, c, v):
+    """``ref[r, c] = v`` as a masked whole-row store."""
+    row = ref[pl.ds(r, 1), :]
+    lanes = lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    ref[pl.ds(r, 1), :] = jnp.where(lanes == c, v, row)
+
+
+def apply_proposal(work_ref, dom_ref, r, c, nr, nc, ua, ud, *,
+                   t_eps: float, t_eps_mu: float):
+    """One elementary update of the cell at (r, c) against its neighbour at
+    (nr, nc), both absolute rows/lanes of the int32 ``work_ref`` — the
+    kernels' single copy of ``repro.core.rules.apply_pair``. ``dom_ref``
+    lives in SMEM; every operand here is a scalar."""
+    s = read_cell(work_ref, r, c)
+    n = read_cell(work_ref, nr, nc)
+
+    same = s == n
+    migrate = ua < t_eps
+    interact = (ua >= t_eps) & (ua < t_eps_mu)
+    reproduce = ua >= t_eps_mu
+    p1 = dom_ref[s, n]
+    p2 = dom_ref[n, s]
+    kill_n = interact & (ud < p1)
+    kill_s = interact & ~kill_n & (ud < p1 + p2)
+    rep_to_n = reproduce & (n == 0)
+    rep_to_s = reproduce & (s == 0)
+    zero = jnp.int32(0)
+    new_s = jnp.where(migrate, n,
+            jnp.where(kill_s, zero,
+            jnp.where(rep_to_s, n, s)))
+    new_n = jnp.where(migrate, s,
+            jnp.where(kill_n, zero,
+            jnp.where(rep_to_n, s, n)))
+    new_s = jnp.where(same, s, new_s)
+    new_n = jnp.where(same, n, new_n)
+
+    write_cell(work_ref, r, c, new_s)
+    write_cell(work_ref, nr, nc, new_n)
+
+
+def apply_tile_proposal(work_ref, dom_ref, dirs_ref, r0, c0, cell, dirn, ua,
+                        ud, *, iw: int, t_eps: float, t_eps_mu: float):
+    """Proposal ``cell`` (an index into the tile interior, row width
+    ``iw``) of the tile whose top-left cell is (r0, c0). Interior cells
+    and their neighbours lie inside the tile, so nothing wraps."""
+    r = r0 + 1 + cell // iw
+    c = c0 + 1 + cell % iw
+    apply_proposal(work_ref, dom_ref, r, c, r + dirs_ref[dirn, 0],
+                   c + dirs_ref[dirn, 1], ua, ud, t_eps=t_eps,
+                   t_eps_mu=t_eps_mu)
+
+
+def band_layout(h: int, w: int, tile_shape: Tuple[int, int]):
+    """(rows of one lattice block, tiles per block). A block holds the
+    fewest whole tile rows that make a multiple of 8 sublanes and divide
+    ``h``, else the whole lattice (a block dim is legal when it is a
+    multiple of 8 or the array's own), and always the full width."""
+    th, tw = tile_shape
+    bh = th * 8 // math.gcd(th, 8)
+    if h % bh:
+        bh = h
+    return bh, (bh // th) * (w // tw)
+
+
+def band_tile(th: int, tw: int, tiles_w: int):
+    """(tile row within the band, tile column, r0, c0) of this program:
+    program (i, j) runs tile j, in raster order, of band i."""
+    j = pl.program_id(1)
+    q = j // tiles_w
+    tj = j % tiles_w
+    return q, tj, q * th, tj * tw
+
+
+def band_program(grid_ref, out_ref, scratch, sweep):
+    """Run ``sweep(work_ref)`` for this program's tile on the band block.
+
+    The first tile of a band loads the band into the int32 working
+    buffer; the last one writes it back. For int32 lattices the working
+    buffer is the output block itself."""
+    j = pl.program_id(1)
+    work = scratch[0] if scratch else out_ref
+
+    @pl.when(j == 0)
+    def _():
+        work[...] = grid_ref[...].astype(jnp.int32)
+
+    sweep(work)
+
+    if scratch:
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _():
+            out_ref[...] = work[...].astype(out_ref.dtype)
+
+
+def band_call(kernel, grid: jax.Array, tile_shape: Tuple[int, int],
+              scalar_specs, interpret: bool):
+    """``pallas_call`` over (row band, tile) programs with the lattice in
+    VMEM as ``band_layout`` blocks; ``scalar_specs`` are the SMEM operands
+    that precede the lattice. ``kernel`` takes the static ``band_tiles``
+    (tiles per band) keyword."""
+    h, w = grid.shape
+    bh, band_tiles = band_layout(h, w, tile_shape)
+    band = pl.BlockSpec((bh, w), lambda i, j: (i, 0))
+    scratch = ([] if grid.dtype == jnp.int32
+               else [pltpu.VMEM((bh, w), jnp.int32)])
+    return pl.pallas_call(
+        functools.partial(kernel, band_tiles=band_tiles),
+        grid=(h // bh, band_tiles),
+        in_specs=[*scalar_specs, band],
+        out_specs=band,
+        out_shape=jax.ShapeDtypeStruct((h, w), grid.dtype),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )
 
 
 def _kernel(cell_ref, dirn_ref, uact_ref, udom_ref, dom_ref, dirs_ref,
-            grid_ref, out_ref, *, t_eps: float, t_eps_mu: float, k: int,
-            iw: int):
-    out_ref[...] = grid_ref[...]
+            grid_ref, out_ref, *scratch, t_eps: float, t_eps_mu: float,
+            k: int, th: int, tw: int, tiles_w: int, band_tiles: int):
+    _, _, r0, c0 = band_tile(th, tw, tiles_w)
+    # raster tile id mod 8: this tile's row of the (8, K) proposal block
+    p = (pl.program_id(0) * band_tiles + pl.program_id(1)) % 8
 
-    def body(j, _):
-        # NB: row index must be a dslice, not a bare int — scalar int
-        # indexing into Refs is rejected by the installed JAX (the
-        # discharge rule calls .shape on every index).
-        row0 = pl.ds(0, 1)
-        cell = pl.load(cell_ref, (row0, pl.ds(j, 1)))[0, 0]
-        dirn = pl.load(dirn_ref, (row0, pl.ds(j, 1)))[0, 0]
-        ua = pl.load(uact_ref, (row0, pl.ds(j, 1)))[0, 0]
-        ud = pl.load(udom_ref, (row0, pl.ds(j, 1)))[0, 0]
+    def sweep(work):
+        def body(jj, _):
+            apply_tile_proposal(
+                work, dom_ref, dirs_ref, r0, c0, cell_ref[p, jj],
+                dirn_ref[p, jj], uact_ref[p, jj], udom_ref[p, jj],
+                iw=tw - 2, t_eps=t_eps, t_eps_mu=t_eps_mu)
+            return 0
 
-        r = 1 + cell // iw
-        c = 1 + cell % iw
-        d = pl.load(dirs_ref, (pl.ds(dirn, 1), slice(None)))[0]
-        nr = r + d[0]
-        nc = c + d[1]
+        lax.fori_loop(0, k, body, 0)
 
-        s = pl.load(out_ref, (pl.ds(r, 1), pl.ds(c, 1)))[0, 0]
-        n = pl.load(out_ref, (pl.ds(nr, 1), pl.ds(nc, 1)))[0, 0]
-        cell_dt = s.dtype
-        s = s.astype(jnp.int32)
-        n = n.astype(jnp.int32)
-
-        # --- inline pure pair rule (repro.core.rules.apply_pair) ---
-        same = s == n
-        migrate = ua < t_eps
-        interact = (ua >= t_eps) & (ua < t_eps_mu)
-        reproduce = ua >= t_eps_mu
-        p1 = pl.load(dom_ref, (pl.ds(s, 1), pl.ds(n, 1)))[0, 0]
-        p2 = pl.load(dom_ref, (pl.ds(n, 1), pl.ds(s, 1)))[0, 0]
-        kill_n = interact & (ud < p1)
-        kill_s = interact & ~kill_n & (ud < p1 + p2)
-        rep_to_n = reproduce & (n == 0)
-        rep_to_s = reproduce & (s == 0)
-        zero = jnp.int32(0)
-        new_s = jnp.where(migrate, n,
-                jnp.where(kill_s, zero,
-                jnp.where(rep_to_s, n, s)))
-        new_n = jnp.where(migrate, s,
-                jnp.where(kill_n, zero,
-                jnp.where(rep_to_n, s, n)))
-        new_s = jnp.where(same, s, new_s)
-        new_n = jnp.where(same, n, new_n)
-
-        pl.store(out_ref, (pl.ds(r, 1), pl.ds(c, 1)),
-                 new_s.astype(cell_dt).reshape(1, 1))
-        pl.store(out_ref, (pl.ds(nr, 1), pl.ds(nc, 1)),
-                 new_n.astype(cell_dt).reshape(1, 1))
-        return 0
-
-    lax.fori_loop(0, k, body, 0)
+    band_program(grid_ref, out_ref, scratch, sweep)
 
 
 def escg_tile_round(grid: jax.Array, cell: jax.Array, dirn: jax.Array,
@@ -99,23 +202,18 @@ def escg_tile_round(grid: jax.Array, cell: jax.Array, dirn: jax.Array,
     """
     h, w = grid.shape
     th, tw = tile_shape
-    gh, gw = h // th, w // tw
     t, k = cell.shape
-    assert t == gh * gw, (t, gh, gw)
-    iw = tw - 2
+    assert t == (h // th) * (w // tw), (t, h, w, tile_shape)
 
     kern = functools.partial(_kernel, t_eps=float(t_eps),
-                             t_eps_mu=float(t_eps_mu), k=int(k), iw=int(iw))
-    prop_spec = pl.BlockSpec((1, k), lambda i, j: (i * gw + j, 0))
-    full = lambda a: pl.BlockSpec(a.shape, lambda i, j: (0,) * a.ndim)
-
-    return pl.pallas_call(
-        kern,
-        grid=(gh, gw),
-        in_specs=[prop_spec, prop_spec, prop_spec, prop_spec,
-                  full(dom), full(dirs),
-                  pl.BlockSpec((th, tw), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((th, tw), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((h, w), grid.dtype),
-        interpret=interpret,
-    )(cell, dirn, u_act, u_dom, dom, dirs, grid)
+                             t_eps_mu=float(t_eps_mu), k=int(k), th=th,
+                             tw=tw, tiles_w=w // tw)
+    # the proposals of 8 consecutive tiles per block: (1, K) is not a
+    # legal block shape, (8, K) is
+    _, band_tiles = band_layout(h, w, tile_shape)
+    prop = pl.BlockSpec((8, k), lambda i, j: ((i * band_tiles + j) // 8, 0),
+                        memory_space=SMEM)
+    call = band_call(kern, grid, tile_shape,
+                     [prop, prop, prop, prop, SMEM_FULL, SMEM_FULL],
+                     interpret)
+    return call(cell, dirn, u_act, u_dom, dom, dirs, grid)

@@ -32,13 +32,17 @@ tile oracle — bit-exact match required (tests/test_kernels.py).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from .escg_update import (SMEM_FULL, apply_proposal, apply_tile_proposal,
+                          band_call, band_program, band_tile)
 from .philox import philox_proposal_fields
 
 
@@ -56,75 +60,48 @@ def check_counter_capacity(n_tiles: int, k_per_tile: int) -> None:
             f"or enlarge the tile")
 
 
-def _apply_proposal(out_ref, dom_ref, dirs_ref, r, c, dirn, ua, ud, *,
-                    t_eps: float, t_eps_mu: float):
-    """One elementary update at absolute (r, c) of ``out_ref`` — the single
-    source of the ESCG action semantics shared by the one-round kernel and
-    the multi-MCS megakernel."""
-    d = pl.load(dirs_ref, (pl.ds(dirn, 1), slice(None)))[0]
-    nr = r + d[0]
-    nc = c + d[1]
+def _tile_proposals(tile_id, round_idx, k0, k1, *, k: int, interior: int,
+                    nbhd: int):
+    """This tile's K proposals as four (1, K) vectors, from Philox counters
+    ``tile_id * K + j``."""
+    j = lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    idx = tile_id * jnp.uint32(k) + j.astype(jnp.uint32)
+    return j, philox_proposal_fields(idx, round_idx, k0, k1, interior, nbhd)
 
-    s = pl.load(out_ref, (pl.ds(r, 1), pl.ds(c, 1)))[0, 0]
-    n = pl.load(out_ref, (pl.ds(nr, 1), pl.ds(nc, 1)))[0, 0]
-    cell_dt = s.dtype
-    s = s.astype(jnp.int32)
-    n = n.astype(jnp.int32)
 
-    same = s == n
-    migrate = ua < t_eps
-    interact = (ua >= t_eps) & (ua < t_eps_mu)
-    reproduce = ua >= t_eps_mu
-    p1 = pl.load(dom_ref, (pl.ds(s, 1), pl.ds(n, 1)))[0, 0]
-    p2 = pl.load(dom_ref, (pl.ds(n, 1), pl.ds(s, 1)))[0, 0]
-    kill_n = interact & (ud < p1)
-    kill_s = interact & ~kill_n & (ud < p1 + p2)
-    rep_to_n = reproduce & (n == 0)
-    rep_to_s = reproduce & (s == 0)
-    zero = jnp.int32(0)
-    new_s = jnp.where(migrate, n,
-            jnp.where(kill_s, zero,
-            jnp.where(rep_to_s, n, s)))
-    new_n = jnp.where(migrate, s,
-            jnp.where(kill_n, zero,
-            jnp.where(rep_to_n, s, n)))
-    new_s = jnp.where(same, s, new_s)
-    new_n = jnp.where(same, n, new_n)
-
-    pl.store(out_ref, (pl.ds(r, 1), pl.ds(c, 1)),
-             new_s.astype(cell_dt).reshape(1, 1))
-    pl.store(out_ref, (pl.ds(nr, 1), pl.ds(nc, 1)),
-             new_n.astype(cell_dt).reshape(1, 1))
+def _pick(vec, lanes, jj):
+    """Scalar ``vec[0, jj]``: a masked sum, since Mosaic reads no single
+    lane of a vector at a dynamic offset."""
+    return jnp.sum(jnp.where(lanes == jj, vec, jnp.zeros_like(vec)))
 
 
 def _kernel(seed_ref, round_ref, off_ref, dom_ref, dirs_ref, grid_ref,
-            out_ref, *, t_eps: float, t_eps_mu: float, k: int, iw: int,
-            interior: int, nbhd: int, gw: int):
-    i = pl.program_id(0).astype(jnp.uint32)
-    j = pl.program_id(1).astype(jnp.uint32)
-    # global raster tile id: program position offset by this shard's first
-    # owned tile, flattened against the GLOBAL tile-grid width
-    tile_id = (off_ref[0, 0] + i) * jnp.uint32(gw) + (off_ref[0, 1] + j)
+            out_ref, *scratch, t_eps: float, t_eps_mu: float, k: int,
+            th: int, tw: int, tiles_w: int, interior: int, nbhd: int,
+            gw: int, band_tiles: int):
+    q, tj, r0, c0 = band_tile(th, tw, tiles_w)
+    # global raster tile id: the program's tile offset by this shard's
+    # first owned tile, flattened against the GLOBAL tile-grid width
+    ti = pl.program_id(0) * (band_tiles // tiles_w) + q
+    tile_id = ((off_ref[0, 0] + ti).astype(jnp.uint32) * jnp.uint32(gw)
+               + (off_ref[0, 1] + tj).astype(jnp.uint32))
 
-    # --- derive this tile's K proposals from counters (vectorized) ---
-    idx = tile_id * jnp.uint32(k) + lax.iota(jnp.uint32, k)
-    cells, dirns, uact, udom = philox_proposal_fields(
-        idx, round_ref[0, 0], seed_ref[0, 0], seed_ref[0, 1], interior,
-        nbhd)
+    def sweep(work):
+        lanes, (cells, dirns, uact, udom) = _tile_proposals(
+            tile_id, round_ref[0, 0], seed_ref[0, 0], seed_ref[0, 1], k=k,
+            interior=interior, nbhd=nbhd)
 
-    out_ref[...] = grid_ref[...]
+        def body(jj, _):
+            apply_tile_proposal(
+                work, dom_ref, dirs_ref, r0, c0, _pick(cells, lanes, jj),
+                _pick(dirns, lanes, jj), _pick(uact, lanes, jj),
+                _pick(udom, lanes, jj), iw=tw - 2, t_eps=t_eps,
+                t_eps_mu=t_eps_mu)
+            return 0
 
-    def body(jj, _):
-        cell = lax.dynamic_index_in_dim(cells, jj, keepdims=False)
-        dirn = lax.dynamic_index_in_dim(dirns, jj, keepdims=False)
-        ua = lax.dynamic_index_in_dim(uact, jj, keepdims=False)
-        ud = lax.dynamic_index_in_dim(udom, jj, keepdims=False)
-        _apply_proposal(out_ref, dom_ref, dirs_ref, 1 + cell // iw,
-                        1 + cell % iw, dirn, ua, ud, t_eps=t_eps,
-                        t_eps_mu=t_eps_mu)
-        return 0
+        lax.fori_loop(0, k, body, 0)
 
-    lax.fori_loop(0, k, body, 0)
+    band_program(grid_ref, out_ref, scratch, sweep)
 
 
 def escg_tile_round_fused(grid: jax.Array, seed: jax.Array,
@@ -148,8 +125,6 @@ def escg_tile_round_fused(grid: jax.Array, seed: jax.Array,
     h, w = grid.shape
     th, tw = tile_shape
     gh, gw = h // th, w // tw
-    iw = tw - 2
-    interior = (th - 2) * (tw - 2)
     if grid_tiles_w is None:
         # single-lattice call: the local tile grid IS the global one.
         # Sharded callers pass grid_tiles_w and guard with the true
@@ -158,80 +133,99 @@ def escg_tile_round_fused(grid: jax.Array, seed: jax.Array,
 
     kern = functools.partial(
         _kernel, t_eps=float(t_eps), t_eps_mu=float(t_eps_mu),
-        k=int(k_per_tile), iw=int(iw), interior=int(interior),
-        nbhd=int(neighbourhood),
+        k=int(k_per_tile), th=th, tw=tw, tiles_w=gw,
+        interior=(th - 2) * (tw - 2), nbhd=int(neighbourhood),
         gw=int(gw if grid_tiles_w is None else grid_tiles_w))
-    seed_arr = seed.reshape(1, 2).astype(jnp.uint32)
-    round_arr = jnp.reshape(round_idx, (1, 1)).astype(jnp.uint32)
     if tile_offset is None:
         tile_offset = jnp.zeros((2,), jnp.uint32)
-    off_arr = jnp.reshape(tile_offset, (1, 2)).astype(jnp.uint32)
-    full = lambda a: pl.BlockSpec(a.shape, lambda i, j: (0,) * a.ndim)
-
-    return pl.pallas_call(
-        kern,
-        grid=(gh, gw),
-        in_specs=[full(seed_arr), full(round_arr), full(off_arr),
-                  full(dom), full(dirs),
-                  pl.BlockSpec((th, tw), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((th, tw), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((h, w), grid.dtype),
-        interpret=interpret,
-    )(seed_arr, round_arr, off_arr, dom, dirs, grid)
+    call = band_call(kern, grid, tile_shape, [SMEM_FULL] * 5, interpret)
+    # scalar operands stay 2-D: under vmap a batched 1-D operand would get
+    # an illegal (1, n) block
+    return call(seed.reshape(1, 2).astype(jnp.uint32),
+                jnp.reshape(round_idx, (1, 1)).astype(jnp.uint32),
+                jnp.reshape(tile_offset, (1, 2)).astype(jnp.int32),
+                dom, dirs, grid)
 
 
 # ------------------------ multi-MCS megakernel ---------------------------- #
 
+# Mosaic's scoped-VMEM cap for the megakernel (v5e has 128 MiB of VMEM
+# per core), and the share of it the resident lattice buffers may take;
+# Mosaic refuses the kernel once they pass the cap.
+MEGA_VMEM_LIMIT_BYTES = 100 * 2 ** 20
+MEGA_LATTICE_BUDGET_BYTES = 96 * 2 ** 20
+
+
+def mega_lattice_bytes(h: int, w: int, cell_dtype) -> int:
+    """VMEM the megakernel keeps resident for an (h, w) lattice: the input
+    and output blocks, plus an int32 working copy for narrow cells."""
+    item = jnp.dtype(cell_dtype).itemsize
+    return h * w * (2 * item + (0 if item == 4 else 4))
+
+
+def check_mega_fits(h: int, w: int, cell_dtype) -> None:
+    """Refuse a lattice the ``k_mcs`` megakernel cannot hold in VMEM."""
+    need = mega_lattice_bytes(h, w, cell_dtype)
+    if need > MEGA_LATTICE_BUDGET_BYTES:
+        raise ValueError(
+            f"k_mcs > 1 keeps the whole {h}x{w} {jnp.dtype(cell_dtype).name}"
+            f" lattice resident in VMEM: {need} bytes exceeds the "
+            f"megakernel's VMEM limit of {MEGA_LATTICE_BUDGET_BYTES} bytes "
+            f"({MEGA_LATTICE_BUDGET_BYTES // 2 ** 20} MiB); use k_mcs=1 "
+            "(one launch per MCS) or a sharded engine")
+
+
+def _wrap(x, n: int):
+    return jnp.where(x >= n, x - n, x)
+
+
 def _mega_kernel(seeds_ref, shifts_ref, off_ref, dom_ref, dirs_ref,
-                 grid_ref, out_ref, counts_ref, *, t_eps: float,
+                 grid_ref, out_ref, counts_ref, *scratch, t_eps: float,
                  t_eps_mu: float, k: int, iw: int, interior: int,
                  nbhd: int, gw: int, lgh: int, lgw: int, th: int, tw: int,
-                 n_steps: int, n_counts: int):
+                 n_steps: int, n_counts: int, count_rows: int):
     """K Monte-Carlo steps over the whole (resident) lattice, one launch.
 
-    The per-tile grid of the single-round kernel is folded into an
-    in-kernel loop — TPU grid iterations run sequentially on a core, so
-    nothing is lost; what is gained is that the K-step shift/sweep/count
-    cycle never leaves VMEM. Each fori_loop step t: torus-roll by
-    -shifts[t] (concat + dynamic_slice — the frame drifts exactly like the
-    jit-level ``jnp.roll`` of the one-round path), sweep every tile with
-    proposals from Philox counters keyed by (seeds[t], global tile id),
-    then bank per-species cell counts into counts_ref[t]."""
+    The tile grid of the single-round kernel is folded into an in-kernel
+    loop — TPU grid iterations run sequentially on a core, so nothing is
+    lost; what is gained is that the K-step shift/sweep/count cycle never
+    leaves VMEM. The torus shift of step t is not applied to the data:
+    the kernel keeps the frame's origin (a, b) — the sum of the shifts so
+    far — and addresses frame cell (x, y) at ((x + a) mod H, (y + b) mod
+    W). The caller rolls the result by the final origin once, which is
+    exactly the frame K jit-level ``jnp.roll`` rounds drift into. Counts
+    are translation-invariant, so step t banks them from the buffer as
+    it stands."""
     h = lgh * th
     w = lgw * tw
-    out_ref[...] = grid_ref[...]
+    work = scratch[0] if scratch else out_ref
+    work[...] = grid_ref[...].astype(jnp.int32)
 
-    def step(t, _):
-        sr = pl.load(shifts_ref, (pl.ds(t, 1), slice(None)))[0]
-        g = out_ref[...]
-        g = lax.dynamic_slice_in_dim(jnp.concatenate([g, g], 0),
-                                     sr[0], h, 0)
-        g = lax.dynamic_slice_in_dim(jnp.concatenate([g, g], 1),
-                                     sr[1], w, 1)
-        out_ref[...] = g
-        seed = pl.load(seeds_ref, (pl.ds(t, 1), slice(None)))[0]
+    def step(t, origin):
+        a = _wrap(origin[0] + shifts_ref[t, 0], h)
+        b = _wrap(origin[1] + shifts_ref[t, 1], w)
 
         def tile_body(tile_idx, _):
             ti = tile_idx // lgw
             tj = tile_idx % lgw
-            tile_id = ((off_ref[0, 0] + ti.astype(jnp.uint32))
+            tile_id = ((off_ref[0, 0] + ti).astype(jnp.uint32)
                        * jnp.uint32(gw)
-                       + (off_ref[0, 1] + tj.astype(jnp.uint32)))
-            idx = tile_id * jnp.uint32(k) + lax.iota(jnp.uint32, k)
-            cells, dirns, uact, udom = philox_proposal_fields(
-                idx, jnp.uint32(0), seed[0], seed[1], interior, nbhd)
-            tr = ti * th
-            tc = tj * tw
+                       + (off_ref[0, 1] + tj).astype(jnp.uint32))
+            lanes, (cells, dirns, uact, udom) = _tile_proposals(
+                tile_id, jnp.uint32(0), seeds_ref[t, 0], seeds_ref[t, 1],
+                k=k, interior=interior, nbhd=nbhd)
 
             def prop_body(jj, _):
-                cell = lax.dynamic_index_in_dim(cells, jj, keepdims=False)
-                dirn = lax.dynamic_index_in_dim(dirns, jj, keepdims=False)
-                ua = lax.dynamic_index_in_dim(uact, jj, keepdims=False)
-                ud = lax.dynamic_index_in_dim(udom, jj, keepdims=False)
-                _apply_proposal(out_ref, dom_ref, dirs_ref,
-                                tr + 1 + cell // iw, tc + 1 + cell % iw,
-                                dirn, ua, ud, t_eps=t_eps,
-                                t_eps_mu=t_eps_mu)
+                cell = _pick(cells, lanes, jj)
+                dirn = _pick(dirns, lanes, jj)
+                x = ti * th + 1 + cell // iw
+                y = tj * tw + 1 + cell % iw
+                apply_proposal(
+                    work, dom_ref, _wrap(x + a, h), _wrap(y + b, w),
+                    _wrap(x + dirs_ref[dirn, 0] + a, h),
+                    _wrap(y + dirs_ref[dirn, 1] + b, w),
+                    _pick(uact, lanes, jj), _pick(udom, lanes, jj),
+                    t_eps=t_eps, t_eps_mu=t_eps_mu)
                 return 0
 
             lax.fori_loop(0, k, prop_body, 0)
@@ -239,14 +233,21 @@ def _mega_kernel(seeds_ref, shifts_ref, off_ref, dom_ref, dirs_ref,
 
         lax.fori_loop(0, lgh * lgw, tile_body, 0)
 
-        gi = out_ref[...].astype(jnp.int32)
-        for s in range(n_counts):       # static unroll over species + 1
-            cnt = jnp.sum((gi == s).astype(jnp.int32))
-            pl.store(counts_ref, (pl.ds(t, 1), pl.ds(s, 1)),
-                     cnt.reshape(1, 1))
-        return 0
+        def count_body(c, acc):
+            r = pl.multiple_of(c * count_rows, count_rows)
+            rows = work[pl.ds(r, count_rows), :]
+            return tuple(a_s + jnp.sum((rows == s).astype(jnp.int32))
+                         for s, a_s in enumerate(acc))
 
-    lax.fori_loop(0, n_steps, step, 0)
+        counts = lax.fori_loop(0, h // count_rows, count_body,
+                               (jnp.int32(0),) * n_counts)
+        for s in range(n_counts):       # static unroll over species + 1
+            counts_ref[t, s] = counts[s]
+        return a, b
+
+    lax.fori_loop(0, n_steps, step, (jnp.int32(0), jnp.int32(0)))
+    if scratch:
+        out_ref[...] = work[...].astype(out_ref.dtype)
 
 
 def escg_tile_rounds_fused(grid: jax.Array, seeds: jax.Array,
@@ -265,36 +266,46 @@ def escg_tile_rounds_fused(grid: jax.Array, seeds: jax.Array,
     path. Returns ``(grid, counts)`` with counts (K, species + 1) int32,
     counts[t] == metrics.counts(grid after step t) — the per-MCS density
     stream the drivers need, banked in-kernel so no intermediate grid
-    round-trips to HBM. The grid stays in the drifted frame, exactly like
-    the roll_back=False one-round path. ``tile_offset``/``grid_tiles_w``
-    key counters by global tile identity when ``grid`` is one shard."""
+    round-trips to HBM. The grid comes back in the drifted frame, exactly
+    like the roll_back=False one-round path. ``tile_offset``/
+    ``grid_tiles_w`` key counters by global tile identity when ``grid`` is
+    one shard. Lattices past ``check_mega_fits`` are refused."""
     h, w = grid.shape
     th, tw = tile_shape
     lgh, lgw = h // th, w // tw
-    iw = tw - 2
-    interior = (th - 2) * (tw - 2)
     n_steps = int(seeds.shape[0])
+    check_mega_fits(h, w, grid.dtype)
     if grid_tiles_w is None:
         check_counter_capacity(lgh * lgw, k_per_tile)
 
     kern = functools.partial(
         _mega_kernel, t_eps=float(t_eps), t_eps_mu=float(t_eps_mu),
-        k=int(k_per_tile), iw=int(iw), interior=int(interior),
+        k=int(k_per_tile), iw=tw - 2, interior=(th - 2) * (tw - 2),
         nbhd=int(neighbourhood),
         gw=int(lgw if grid_tiles_w is None else grid_tiles_w),
-        lgh=int(lgh), lgw=int(lgw), th=int(th), tw=int(tw),
-        n_steps=n_steps, n_counts=int(species) + 1)
-    seeds_arr = seeds.reshape(n_steps, 2).astype(jnp.uint32)
-    shifts_arr = shifts.reshape(n_steps, 2).astype(jnp.int32)
+        lgh=lgh, lgw=lgw, th=th, tw=tw, n_steps=n_steps,
+        n_counts=int(species) + 1, count_rows=math.gcd(h, 8))
+    seeds = seeds.reshape(n_steps, 2).astype(jnp.uint32)
+    shifts = shifts.reshape(n_steps, 2).astype(jnp.int32)
     if tile_offset is None:
-        tile_offset = jnp.zeros((2,), jnp.uint32)
-    off_arr = jnp.reshape(tile_offset, (1, 2)).astype(jnp.uint32)
+        tile_offset = jnp.zeros((2,), jnp.int32)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
 
-    # single program, whole lattice resident: no grid, full-array refs
-    return pl.pallas_call(
+    # single program, whole lattice resident: no grid, full-array blocks
+    grid, counts = pl.pallas_call(
         kern,
+        in_specs=[SMEM_FULL] * 5 + [vmem],
+        out_specs=(vmem, SMEM_FULL),
         out_shape=(jax.ShapeDtypeStruct((h, w), grid.dtype),
                    jax.ShapeDtypeStruct((n_steps, int(species) + 1),
                                         jnp.int32)),
+        scratch_shapes=([] if grid.dtype == jnp.int32
+                        else [pltpu.VMEM((h, w), jnp.int32)]),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=MEGA_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(seeds_arr, shifts_arr, off_arr, dom, dirs, grid)
+    )(seeds, shifts, jnp.reshape(tile_offset, (1, 2)).astype(jnp.int32),
+      dom, dirs, grid)
+    origin = jnp.sum(shifts, axis=0)
+    return jnp.roll(grid, (-(origin[0] % h), -(origin[1] % w)),
+                    (0, 1)), counts

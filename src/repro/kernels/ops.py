@@ -1,7 +1,8 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels target TPU and are validated via the interpreter per the brief).
+On a TPU the kernels always compile through Mosaic. Pallas interpret mode
+is the CPU path the tests run on (``JAX_PLATFORMS=cpu``), never a
+fallback on the chip.
 """
 from __future__ import annotations
 
@@ -19,7 +20,8 @@ from . import escg_update_fused as escg_fused_kernel
 from . import philox as philox_kernel
 
 
-def _default_interpret(interpret: Optional[bool]) -> bool:
+def _default_interpret(interpret: Optional[bool] = None) -> bool:
+    """Interpret mode exactly when no TPU backs the default device."""
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
@@ -42,13 +44,12 @@ def _escg_round_impl(grid, cell, dirn, u_act, u_dom, shift, dom,
 
 def escg_round(grid: jax.Array, props: ProposalBatch, shift: jax.Array,
                dom: jax.Array, tile_shape: Tuple[int, int], t_eps: float,
-               t_eps_mu: float, interpret: Optional[bool] = None,
-               roll_back: bool = True) -> jax.Array:
+               t_eps_mu: float, roll_back: bool = True) -> jax.Array:
     """Drop-in Pallas replacement for core.sublattice.run_round."""
     return _escg_round_impl(grid, props.cell, props.dirn, props.u_act,
                             props.u_dom, shift, dom, tile_shape,
                             float(t_eps), float(t_eps_mu),
-                            _default_interpret(interpret), roll_back)
+                            _default_interpret(), roll_back)
 
 
 def philox_bits(n: int, seed: Tuple[int, int] = (0, 0), stream: int = 0,
@@ -93,8 +94,7 @@ def _escg_round_fused_impl(grid, seed, round_idx, shift, tile_offset, dom,
 
 def escg_round_fused(grid, seed, round_idx, shift, dom, tile_shape,
                      k_per_tile, t_eps, t_eps_mu, neighbourhood=4,
-                     interpret=None, roll_back=True, tile_offset=None,
-                     grid_tiles_w=None):
+                     roll_back=True, tile_offset=None, grid_tiles_w=None):
     """Fused-PRNG sublattice round: proposals derived in-kernel from Philox
     counters (zero proposal HBM traffic; see escg_update_fused).
     ``tile_offset``/``grid_tiles_w`` key the counters by GLOBAL tile
@@ -104,7 +104,7 @@ def escg_round_fused(grid, seed, round_idx, shift, dom, tile_shape,
     return _escg_round_fused_impl(grid, seed, round_idx, shift, tile_offset,
                                   dom, tile_shape, k_per_tile, float(t_eps),
                                   float(t_eps_mu), neighbourhood,
-                                  _default_interpret(interpret), roll_back,
+                                  _default_interpret(), roll_back,
                                   grid_tiles_w)
 
 
@@ -126,10 +126,10 @@ def _escg_rounds_fused_impl(grid, seeds, shifts, tile_offset, dom,
 
 def escg_rounds_fused(grid, seeds, shifts, dom, tile_shape, k_per_tile,
                       t_eps, t_eps_mu, species, neighbourhood=4,
-                      interpret=None, tile_offset=None, grid_tiles_w=None):
+                      tile_offset=None, grid_tiles_w=None):
     """K fused MCS in ONE pallas_call (the ``k_mcs`` megakernel): the
-    per-step torus roll happens IN-KERNEL, so unlike ``escg_round_fused``
-    there is no jit-level roll and no roll_back knob — the grid comes back
+    per-step torus shifts are applied in-kernel, so unlike
+    ``escg_round_fused`` there is no roll_back knob — the grid comes back
     in the drifted frame of the last step, with per-step species counts
     (K, species + 1) banked alongside (see escg_update_fused)."""
     if tile_offset is None:
@@ -137,6 +137,5 @@ def escg_rounds_fused(grid, seeds, shifts, dom, tile_shape, k_per_tile,
     return _escg_rounds_fused_impl(grid, seeds, shifts, tile_offset, dom,
                                    tile_shape, k_per_tile, float(t_eps),
                                    float(t_eps_mu), int(species),
-                                   neighbourhood,
-                                   _default_interpret(interpret),
+                                   neighbourhood, _default_interpret(),
                                    grid_tiles_w)

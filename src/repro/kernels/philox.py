@@ -77,9 +77,14 @@ def philox_proposal_fields(idx, round_idx, k0, k1, interior: int,
     x0, x1, x2, x3 = philox_rounds(idx, c1, zeros, zeros, k0, k1)
     cell = (x0 % jnp.uint32(interior)).astype(jnp.int32)
     dirn = (x1 % jnp.uint32(nbhd)).astype(jnp.int32)
-    u_act = (x2 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2 ** -24)
-    u_dom = (x3 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2 ** -24)
-    return cell, dirn, u_act, u_dom
+    return cell, dirn, _top24_uniform(x2), _top24_uniform(x3)
+
+
+def _top24_uniform(x):
+    """Uniform float32 in [0, 1) from the top 24 bits of a uint32 word.
+    They fit in int32, and Mosaic casts int32 (not uint32) to float."""
+    top = (x >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(2 ** -24)
 
 
 def _kernel(seed_ref, out_ref, *, block: int, base_stream: int):

@@ -62,7 +62,7 @@ import argparse
 import os
 import re
 import time
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
@@ -73,6 +73,7 @@ from ..core import io as io_mod
 from ..core.params import EscgParams, add_cli_args, params_from_args
 from ..core.simulation import simulate
 from ..core.trials import run_trials
+from .compile_cache import enable_compile_cache
 
 # ---------------------- registry matrices (docs) -------------------------- #
 # Both README tables — engines and scenarios — are generated from their
@@ -298,9 +299,10 @@ def scenario_setup(args, ap: argparse.ArgumentParser):
     return sc, params, dom
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> None:
     ap = build_parser()
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.list_engines or args.list_scenarios:
         for flagged, drift_fn, md_fn, text_fn, what in (

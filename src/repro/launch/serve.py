@@ -63,6 +63,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import loadgen
     from repro.serve.server import ScenarioServer
 
@@ -72,6 +73,7 @@ def main(argv=None) -> int:
         print(f"wrote {len(reqs)} requests to {args.emitTrace}")
         return 0
 
+    enable_compile_cache()
     server = ScenarioServer(max_batch_trials=args.maxBatchTrials,
                             cache_entries=args.cacheEntries,
                             max_responses=args.maxResponses)

@@ -8,6 +8,11 @@ import textwrap
 
 import pytest
 
+# Entry points that tests call in-process or as subprocesses place JAX's
+# persistent compilation cache (repro.launch.compile_cache); the tests
+# themselves stay cache-free. Set before any test module imports jax.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 

@@ -69,7 +69,7 @@ def test_halo_roll_round_trip_random_shifts(subproc):
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core.sharded import shard_shift2d
         from repro.parallel.sharding import lattice_mesh
 
@@ -84,7 +84,7 @@ def test_halo_roll_round_trip_random_shifts(subproc):
             return shard_map(f, mesh=mesh,
                              in_specs=(P("rows", "cols"), P()),
                              out_specs=P("rows", "cols"),
-                             check_rep=False)(x, s)
+                             check_vma=False)(x, s)
 
         rng = random.Random("halo_roll_round_trip")
         for i in range(12):

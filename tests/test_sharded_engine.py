@@ -337,7 +337,7 @@ def test_halo_roll_matches_global_roll(subproc):
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core.sharded import shard_shift2d
         from repro.parallel.sharding import lattice_mesh
 
@@ -350,7 +350,7 @@ def test_halo_roll_matches_global_roll(subproc):
                         reverse=reverse)
             return shard_map(f, mesh=mesh, in_specs=(P("rows", "cols"), P()),
                              out_specs=P("rows", "cols"),
-                             check_rep=False)(x, s)
+                             check_vma=False)(x, s)
 
         for sy in (0, 3, 7):
             for sx in (0, 5, 15):
