@@ -1,7 +1,7 @@
 """Core ESCG engine — the paper's contribution as a composable JAX module."""
 from . import batched, dominance, engines, io, lattice, metrics, observables
 from . import park, reference, results, rng, rules, scenarios, simulation
-from . import sublattice, trials
+from . import sublattice, tracing, trials
 from .engines import BuiltEngine, EngineCaps, EngineSpec, engine_names
 from .engines import engine_specs, get_engine, register
 from .params import EscgParams
@@ -32,5 +32,5 @@ __all__ = [
     "make_scenario", "compose", "decompose",
     "batched", "dominance", "engines", "io", "lattice", "metrics",
     "observables", "park", "reference", "results", "rng", "rules",
-    "scenarios", "simulation", "sublattice", "trials",
+    "scenarios", "simulation", "sublattice", "tracing", "trials",
 ]

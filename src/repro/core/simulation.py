@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import dominance as dom_mod
-from . import engines, lattice, metrics
+from . import engines, lattice, metrics, tracing
 from . import observables as obs_mod
 from .params import EscgParams
 from .results import decode_observables, encode_observables
@@ -301,8 +301,13 @@ def simulate(params: EscgParams,
     ``print_frequency`` density path reads the same flushed rows). The
     ring must hold a full chunk (``obs_capacity`` >= effective chunk, or
     0 = auto-size to one chunk).
+
+    Each chunk boundary runs in the ``escg.*`` spans of ``core/tracing.py``
+    and is kept, with its count of blocking reads, in
+    ``tracing.last_run()``.
     """
     from .scenarios import resolve_config  # lazy: scenarios imports core
+    loop = tracing.begin("simulate")
     engine_config, run_config = _resolve_call_form(
         "simulate", params, engine_config, run_config, engine, run)
     params, dom = resolve_config(params, dom, engine_config, run_config)
@@ -344,27 +349,40 @@ def simulate(params: EscgParams,
 
     while mcs_done < p.mcs:
         n_mcs = min(p.chunk_mcs, p.mcs - mcs_done)
-        if obs_on:
-            grid, key, ring, pos, kept, att = chunk_fn(grid, key, ring, pos,
-                                                       n_mcs)
-            # ONE device->host transfer per chunk: the flushed ring rows
-            # carry every per-MCS statistic, counts included
-            rows_h = obs_mod.ring_flush(np.asarray(ring), mcs_done,
-                                        mcs_done + n_mcs)
-            rows_all.append(rows_h)
-            cnts_h = pipe.counts_from_rows(rows_h, p.species)
-        else:
-            grid, key, cnts, kept, att = chunk_fn(grid, key, n_mcs)
-            cnts_h = np.asarray(cnts)
-        hist.append(cnts_h)
-        kept_total += int(kept)
-        att_total += int(att)
-        mcs_done += n_mcs
-        alive = (cnts_h[:, 1:] > 0).sum(axis=1)
-        if stop_on_stasis and stasis_mcs < 0 and np.any(alive <= 1):
-            stasis_mcs = mcs_done - n_mcs + int(np.argmax(alive <= 1)) + 1
-        for hook in hooks:
-            hook(mcs_done, grid, cnts_h)
+        with loop.span(tracing.DISPATCH):
+            if obs_on:
+                grid, key, ring, pos, kept, att = chunk_fn(grid, key, ring,
+                                                           pos, n_mcs)
+            else:
+                grid, key, cnts, kept, att = chunk_fn(grid, key, n_mcs)
+        # the flushed ring rows carry every per-MCS statistic, counts
+        # included: no separate per-MCS counts transfer
+        per_mcs = ring if obs_on else cnts
+        with loop.span(tracing.WAIT):
+            jax.block_until_ready((per_mcs, kept, att))
+        with loop.span(tracing.READBACK):
+            per_mcs_h = loop.read(per_mcs)
+            kept_h, att_h = int(loop.read(kept)), int(loop.read(att))
+        with loop.span(tracing.HOST_STATS):
+            if obs_on:
+                rows_h = obs_mod.ring_flush(per_mcs_h, mcs_done,
+                                            mcs_done + n_mcs)
+                rows_all.append(rows_h)
+                cnts_h = pipe.counts_from_rows(rows_h, p.species)
+            else:
+                cnts_h = per_mcs_h
+            hist.append(cnts_h)
+            kept_total += kept_h
+            att_total += att_h
+            mcs_done += n_mcs
+            alive = (cnts_h[:, 1:] > 0).sum(axis=1)
+            if stop_on_stasis and stasis_mcs < 0 and np.any(alive <= 1):
+                stasis_mcs = (mcs_done - n_mcs
+                              + int(np.argmax(alive <= 1)) + 1)
+        with loop.span(tracing.HOOKS):
+            for hook in hooks:
+                hook(mcs_done, grid, cnts_h)
+        loop.close(mcs_done)
         if stop_on_stasis and stasis_mcs >= 0:
             break
 

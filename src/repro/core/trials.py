@@ -53,7 +53,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from . import dominance as dom_mod
-from . import engines, lattice, metrics
+from . import engines, lattice, metrics, tracing
 from . import observables as obs_mod
 from .params import EscgParams
 from .results import decode_observables, encode_observables
@@ -534,9 +534,14 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
     observable streams are flush-schedule invariant (identical for
     ``async_stats`` True/False and any chunk length, capacity
     permitting).
+
+    Each chunk boundary runs in the ``escg.*`` spans of ``core/tracing.py``
+    and is kept, with its count of blocking reads, in
+    ``tracing.last_run()``.
     """
     from .scenarios import resolve_config  # lazy: scenarios imports core
     from .simulation import _resolve_call_form  # lazy: avoid cycle
+    loop = tracing.begin("run_trials")
     engine_config, run_config = _resolve_call_form(
         "run_trials", params, engine_config, run_config, engine, run)
     params, dom = resolve_config(params, dom, engine_config, run_config)
@@ -628,7 +633,7 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
     done = 0
 
     # One chunk is kept in flight ahead of the host (async_stats): the
-    # np.asarray() below blocks on the chunk being *consumed* while the
+    # wait below blocks on the chunk being *consumed* while the
     # speculatively dispatched successor already computes. On a stasis
     # early-exit the in-flight chunk is simply dropped — its outputs are
     # never read, so statistics and mcs_completed are schedule-independent.
@@ -639,36 +644,51 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
         return g, k, None, None, cnts, alive, kept, att
 
     m = min(chunk_len, n_mcs)
-    out = dispatch(grids, keys, ring, pos, m) if n_mcs else None
+    out = None
+    if n_mcs:
+        with loop.span(tracing.DISPATCH):
+            out = dispatch(grids, keys, ring, pos, m)
     while out is not None:
         grids, keys, ring, pos, cnts, alive, kept, att = out
         m_next = min(chunk_len, n_mcs - done - m)
-        out = (dispatch(grids, keys, ring, pos, m_next)
-               if m_next and async_stats else None)
+        out = None
+        if m_next and async_stats:
+            with loop.span(tracing.DISPATCH):
+                out = dispatch(grids, keys, ring, pos, m_next)
 
-        alive_h = np.asarray(alive)                  # (n_pad, m, S) bool
-        if obs_on:
-            # one flush per CONSUMED chunk (the in-flight speculative
-            # chunk past an early-exit is dropped unflushed)
-            rows_all.append(obs_mod.ring_flush(np.asarray(ring), done,
-                                               done + m))
-        final_cnts = np.asarray(cnts)
-        kept_tot += int(np.asarray(kept)[:n_trials].sum())
-        att_tot += int(np.asarray(att)[:n_trials].sum())
+        # the ring is already an input of the successor; it is an output
+        # of the same execution as these, so it is ready when they are
+        with loop.span(tracing.WAIT):
+            jax.block_until_ready((alive, cnts, kept, att))
+        with loop.span(tracing.READBACK):
+            alive_h = loop.read(alive)                # (n_pad, m, S) bool
+            ring_h = loop.read(ring) if obs_on else None
+            final_cnts = loop.read(cnts)
+            kept_h, att_h = loop.read(kept), loop.read(att)
+        with loop.span(tracing.HOST_STATS):
+            if obs_on:
+                # one flush per CONSUMED chunk (the in-flight speculative
+                # chunk past an early-exit is dropped unflushed)
+                rows_all.append(obs_mod.ring_flush(ring_h, done, done + m))
+            kept_tot += int(kept_h[:n_trials].sum())
+            att_tot += int(att_h[:n_trials].sum())
 
-        first_dead = _first_true_mcs(~alive_h, done)     # (n_pad, S)
-        ext = np.where((ext < 0) & (first_dead > 0), first_dead, ext)
-        first_stasis = _first_true_mcs(alive_h.sum(axis=2) <= 1, done)
-        stasis = np.where((stasis < 0) & (first_stasis > 0),
-                          first_stasis, stasis)
-        surv = alive_h[:, -1, :]
-        done += m
-        for hook in hooks:
-            hook(done, surv[:n_trials].sum(axis=1))
+            first_dead = _first_true_mcs(~alive_h, done)     # (n_pad, S)
+            ext = np.where((ext < 0) & (first_dead > 0), first_dead, ext)
+            first_stasis = _first_true_mcs(alive_h.sum(axis=2) <= 1, done)
+            stasis = np.where((stasis < 0) & (first_stasis > 0),
+                              first_stasis, stasis)
+            surv = alive_h[:, -1, :]
+            done += m
+        with loop.span(tracing.HOOKS):
+            for hook in hooks:
+                hook(done, surv[:n_trials].sum(axis=1))
+        loop.close(done)
         if stop_on_stasis and (stasis[:n_trials] >= 0).all():
             break
         if m_next and out is None:                   # async_stats=False
-            out = dispatch(grids, keys, ring, pos, m_next)
+            with loop.span(tracing.DISPATCH):
+                out = dispatch(grids, keys, ring, pos, m_next)
         m = m_next
 
     observables = {}

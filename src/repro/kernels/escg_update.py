@@ -147,11 +147,12 @@ def band_program(grid_ref, out_ref, scratch, sweep):
 
 
 def band_call(kernel, grid: jax.Array, tile_shape: Tuple[int, int],
-              scalar_specs, interpret: bool):
+              scalar_specs, interpret: bool, name: str):
     """``pallas_call`` over (row band, tile) programs with the lattice in
     VMEM as ``band_layout`` blocks; ``scalar_specs`` are the SMEM operands
     that precede the lattice. ``kernel`` takes the static ``band_tiles``
-    (tiles per band) keyword."""
+    (tiles per band) keyword. ``name`` is the kernel's stable name, which
+    the compiled op and the profiler's trace carry."""
     h, w = grid.shape
     bh, band_tiles = band_layout(h, w, tile_shape)
     band = pl.BlockSpec((bh, w), lambda i, j: (i, 0))
@@ -167,6 +168,7 @@ def band_call(kernel, grid: jax.Array, tile_shape: Tuple[int, int],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )
 
 
@@ -215,5 +217,5 @@ def escg_tile_round(grid: jax.Array, cell: jax.Array, dirn: jax.Array,
                         memory_space=SMEM)
     call = band_call(kern, grid, tile_shape,
                      [prop, prop, prop, prop, SMEM_FULL, SMEM_FULL],
-                     interpret)
+                     interpret, "escg_update")
     return call(cell, dirn, u_act, u_dom, dom, dirs, grid)

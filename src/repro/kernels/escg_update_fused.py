@@ -138,7 +138,8 @@ def escg_tile_round_fused(grid: jax.Array, seed: jax.Array,
         gw=int(gw if grid_tiles_w is None else grid_tiles_w))
     if tile_offset is None:
         tile_offset = jnp.zeros((2,), jnp.uint32)
-    call = band_call(kern, grid, tile_shape, [SMEM_FULL] * 5, interpret)
+    call = band_call(kern, grid, tile_shape, [SMEM_FULL] * 5, interpret,
+                     "escg_round_fused")
     # scalar operands stay 2-D: under vmap a batched 1-D operand would get
     # an illegal (1, n) block
     return call(seed.reshape(1, 2).astype(jnp.uint32),
@@ -304,6 +305,7 @@ def escg_tile_rounds_fused(grid: jax.Array, seeds: jax.Array,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=MEGA_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="escg_rounds_fused",
     )(seeds, shifts, jnp.reshape(tile_offset, (1, 2)).astype(jnp.int32),
       dom, dirs, grid)
     origin = jnp.sum(shifts, axis=0)

@@ -68,7 +68,7 @@ import jax
 import numpy as np
 
 from ..core import dominance as dom_mod
-from ..core import engines, scenarios
+from ..core import engines, scenarios, tracing
 from ..core import io as io_mod
 from ..core.params import EscgParams, add_cli_args, params_from_args
 from ..core.simulation import simulate
@@ -201,6 +201,24 @@ def print_scenario_matrix() -> None:
         print(f"{'':15} {c.description}")
 
 
+def chunk_rates(updates_per_mcs: int) -> str:
+    """The last run's speed from its chunk records (``core/tracing.py``):
+    the first chunk's seconds, which hold set-up, tracing and compiling,
+    on their own, then the update rate over the chunks after it."""
+    run = tracing.last_run()
+    if run is None or run.first is None:
+        return "no chunk ran"
+    first, last = run.first, run.chunks[-1]
+    first_s = first.end_s - run.start_s
+    if last is first:
+        return (f"one chunk, {first.mcs * updates_per_mcs / first_s:.3g} "
+                "updates/s with its compile included")
+    later_mcs, later_s = last.mcs - first.mcs, last.end_s - first.end_s
+    return (f"first chunk {first_s:.2f}s with its compile, then "
+            f"{later_mcs * updates_per_mcs / later_s:.3g} updates/s over "
+            f"{later_mcs} MCS")
+
+
 # ------------------------------ trial mode -------------------------------- #
 
 def run_trial_batch(params: EscgParams, dom: np.ndarray, n_trials: int,
@@ -220,11 +238,10 @@ def run_trial_batch(params: EscgParams, dom: np.ndarray, n_trials: int,
                      hooks=[progress], engine=eng_cfg, run=run_cfg)
     dt = time.time() - t0
 
-    upd = res.mcs_completed * params.n_cells * n_trials
     print(f"[escg] {n_trials} trials x {params.height}x{params.length} "
           f"species={params.species} engine={params.engine} on "
           f"{res.n_devices} device(s): {res.mcs_completed} MCS in {dt:.2f}s "
-          f"({upd / max(dt, 1e-9):.3g} updates/s aggregate)")
+          f"({chunk_rates(params.n_cells * n_trials)})")
     print(f"[escg] survival probabilities: "
           f"{np.round(res.survival_probabilities(), 4)}")
     print(f"[escg] survivors histogram:    "
@@ -410,7 +427,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     total_mcs = start_mcs + res.mcs_completed
     print(f"[escg] {params.height}x{params.length} species={params.species}"
           f" engine={params.engine}: {res.mcs_completed} MCS in {dt:.2f}s"
-          f" ({res.mcs_completed * n / max(dt, 1e-9):.3g} updates/s)")
+          f" ({chunk_rates(n)})")
     if res.stasis_mcs >= 0:
         print(f"[escg] stasis (monoculture/dead) at MCS "
               f"{start_mcs + res.stasis_mcs}")

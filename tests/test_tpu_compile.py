@@ -13,6 +13,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -57,9 +58,15 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _compile_for_chip(fn, *args):
+def _compile_for_chip(fn, *args, kernel):
+    """Compile ``fn`` for the chip; the kernel must reach Mosaic as a
+    custom call that carries its stable name (under ``vmap`` with a
+    ``vmap_`` prefix), which the profiler's trace then shows."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "the kernel did not reach Mosaic"
+    assert re.search(rf"%\w*{kernel}\w*(\.\d+)? = .* custom-call\(",
+                     text), \
+        f"no custom call named {kernel!r}"
 
 
 def _setup(h, w, tile=TILE):
@@ -90,7 +97,7 @@ def test_plain_kernel_compiles_at_3200(one_chip, no_compile_cache,
         jax.ShapeDtypeStruct((L, L), jnp.dtype(cell_dtype),
                              sharding=one_chip),
         prop(jnp.int32), prop(jnp.int32), prop(jnp.float32),
-        prop(jnp.float32), _dom(one_chip))
+        prop(jnp.float32), _dom(one_chip), kernel="escg_update")
 
 
 @pytest.mark.parametrize("cell_dtype", ["int32", "int8"])
@@ -108,7 +115,7 @@ def test_fused_kernel_compiles_at_3200(one_chip, no_compile_cache,
         jax.ShapeDtypeStruct((L, L), jnp.dtype(cell_dtype),
                              sharding=one_chip),
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
-        _dom(one_chip))
+        _dom(one_chip), kernel="escg_round_fused")
 
 
 def test_fused_kernel_compiles_for_a_trial_batch(one_chip,
@@ -128,7 +135,7 @@ def test_fused_kernel_compiles_for_a_trial_batch(one_chip,
         jax.vmap(round_, in_axes=(0, 0, None)),
         jax.ShapeDtypeStruct((16, h, w), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((16, 2), jnp.uint32, sharding=one_chip),
-        _dom(one_chip))
+        _dom(one_chip), kernel="escg_round_fused")
 
 
 def _largest_accepted_side(tw: int) -> int:
@@ -152,7 +159,7 @@ def _mega(one_chip, side):
         jax.ShapeDtypeStruct((side, side), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((steps, 2), jnp.uint32, sharding=one_chip),
         jax.ShapeDtypeStruct((steps, 2), jnp.int32, sharding=one_chip),
-        _dom(one_chip))
+        _dom(one_chip), kernel="escg_rounds_fused")
 
 
 def test_megakernel_compiles_at_its_largest_accepted_size(
