@@ -49,14 +49,24 @@ def apply_pair(s: jax.Array, n: jax.Array, u_act: jax.Array,
     cell_dt = s.dtype
     s = s.astype(jnp.int32)
     n = n.astype(jnp.int32)
+    new_s, new_n = pair_update(s, n, u_act, u_dom, dom[s, n], dom[n, s],
+                               t_eps, t_eps_mu)
+    return new_s.astype(cell_dt), new_n.astype(cell_dt)
+
+
+def pair_update(s: jax.Array, n: jax.Array, u_act: jax.Array,
+                u_dom: jax.Array, p1: jax.Array, p2: jax.Array,
+                t_eps: float, t_eps_mu: float
+                ) -> Tuple[jax.Array, jax.Array]:
+    """``apply_pair`` on int32 species with the dominance entries already
+    looked up: p1 = D[s, n], p2 = D[n, s]. Kernels that cannot gather
+    from D (scalar SMEM reads, one-hot lookups per lane) call this."""
     same = s == n
 
     migrate = u_act < t_eps
     interact = (u_act >= t_eps) & (u_act < t_eps_mu)
     reproduce = u_act >= t_eps_mu
 
-    p1 = dom[s, n]
-    p2 = dom[n, s]
     kill_n = interact & (u_dom < p1)
     kill_s = interact & ~kill_n & (u_dom < p1 + p2)
 
@@ -73,7 +83,7 @@ def apply_pair(s: jax.Array, n: jax.Array, u_act: jax.Array,
 
     new_s = jnp.where(same, s, new_s)
     new_n = jnp.where(same, n, new_n)
-    return new_s.astype(cell_dt), new_n.astype(cell_dt)
+    return new_s, new_n
 
 
 def apply_pair_reference(s: int, n: int, u_act: float, u_dom: float,

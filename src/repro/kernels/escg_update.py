@@ -38,6 +38,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.rules import pair_update
+
 SMEM = pltpu.SMEM
 
 
@@ -62,32 +64,13 @@ def write_cell(ref, r, c, v):
 def apply_proposal(work_ref, dom_ref, r, c, nr, nc, ua, ud, *,
                    t_eps: float, t_eps_mu: float):
     """One elementary update of the cell at (r, c) against its neighbour at
-    (nr, nc), both absolute rows/lanes of the int32 ``work_ref`` — the
-    kernels' single copy of ``repro.core.rules.apply_pair``. ``dom_ref``
-    lives in SMEM; every operand here is a scalar."""
+    (nr, nc), both absolute rows/lanes of the int32 ``work_ref``, by
+    ``repro.core.rules.pair_update``. ``dom_ref`` lives in SMEM; every
+    operand here is a scalar."""
     s = read_cell(work_ref, r, c)
     n = read_cell(work_ref, nr, nc)
-
-    same = s == n
-    migrate = ua < t_eps
-    interact = (ua >= t_eps) & (ua < t_eps_mu)
-    reproduce = ua >= t_eps_mu
-    p1 = dom_ref[s, n]
-    p2 = dom_ref[n, s]
-    kill_n = interact & (ud < p1)
-    kill_s = interact & ~kill_n & (ud < p1 + p2)
-    rep_to_n = reproduce & (n == 0)
-    rep_to_s = reproduce & (s == 0)
-    zero = jnp.int32(0)
-    new_s = jnp.where(migrate, n,
-            jnp.where(kill_s, zero,
-            jnp.where(rep_to_s, n, s)))
-    new_n = jnp.where(migrate, s,
-            jnp.where(kill_n, zero,
-            jnp.where(rep_to_n, s, n)))
-    new_s = jnp.where(same, s, new_s)
-    new_n = jnp.where(same, n, new_n)
-
+    new_s, new_n = pair_update(s, n, ua, ud, dom_ref[s, n], dom_ref[n, s],
+                               t_eps, t_eps_mu)
     write_cell(work_ref, r, c, new_s)
     write_cell(work_ref, nr, nc, new_n)
 

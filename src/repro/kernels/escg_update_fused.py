@@ -1,11 +1,11 @@
-"""Fused-PRNG sublattice kernel (§Perf H3 iter-2, beyond-paper).
+"""Fused-PRNG sublattice kernels (§Perf H3 iter-2, beyond-paper).
 
 The paper pre-generates random-number buffers in device memory and tunes
-their size (--numRandoms, Fig 4.2). This kernel ELIMINATES that traffic and
-the tuning knob: each tile derives its proposals from Philox-4x32 counters
-*inside* the kernel, in VMEM, at the moment of consumption — 16 bytes per
-elementary update of HBM traffic (4 random words) drop to zero; what
-remains is the grid itself.
+their size (--numRandoms, Fig 4.2). These kernels ELIMINATE that traffic
+and the tuning knob: each tile derives its proposals from Philox-4x32
+counters *inside* the kernel, in VMEM, at the moment of consumption — 16
+bytes per elementary update of HBM traffic (4 random words) drop to zero;
+what remains is the grid itself.
 
 Counter layout (``kernels.philox.philox_proposal_fields``): c0 = global
 tile_id * K + j (proposal index), c1 = round index, c2 = c3 = 0; key = two
@@ -17,11 +17,34 @@ while interior < 2^10. ``check_counter_capacity`` guards the other edge:
 c0 = tile_id * K + j must not wrap uint32, or distant tiles would
 silently alias each other's streams.
 
+**One round (``escg_tile_round_fused``): tiles on lanes.** Tiles are
+disjoint and only their interiors are proposed, so every tile can play
+its proposal j at the same time. The shifted (H, W) lattice is re-laid
+(by XLA, around the kernel) as a tile-major int32 array of shape
+(th * tw, T_pad): row p = r * tw + c is a cell position within a tile,
+lane t is raster tile t, and T is padded to whole lane blocks of 128 or
+256 lanes (``lane_layout``) with dummy tiles whose results are dropped.
+The kernel's 1-D grid walks the lane blocks. In each, proposal j of
+every tile of the block is one set of vector ops: Philox for eight j at
+a time as full (8, lanes) vregs; cell and neighbour rows per lane
+without division; one-hot reads (row iota == row, select, sum over
+rows); the dominance lookup the same way, one-hot over the (S + 1)^2
+entries of the table; the rules of ``repro.core.rules.pair_update``; one
+masked select that writes both cells. Within a tile the proposals keep
+their order, so the result is bit-identical to playing the tiles one by
+one. Narrow (int8) lattices
+are widened in the re-layout and narrowed on the way back.
+
+**The ``k_mcs`` megakernel (``escg_tile_rounds_fused``)** keeps the
+whole lattice resident in VMEM and plays each tile's proposals as a
+scalar chain: TPU grid iterations run sequentially on a core, so folding
+the tile grid into an in-kernel loop loses nothing there.
+
 **Global tile identity.** ``tile_offset``/``grid_tiles_w`` let a shard of
 a domain-decomposed lattice derive the SAME counters the single-device
-kernel would: the program's (i, j) position is offset by the shard's
-first owned tile and raster-flattened against the GLOBAL tile-grid width.
-That is the whole multi-device contract — the sharded engines'
+kernel would: a tile's (i, j) position is offset by the shard's first
+owned tile and raster-flattened against the GLOBAL tile-grid width. That
+is the whole multi-device contract — the sharded engines'
 ``local_kernel='fused'`` path stays bit-identical to ``pallas_fused`` for
 every mesh factorization while no proposal array ever touches HBM
 (DESIGN.md §6).
@@ -37,13 +60,17 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .escg_update import (SMEM_FULL, apply_proposal, apply_tile_proposal,
-                          band_call, band_program, band_tile)
+from ..core.rules import pair_update
+from .escg_update import SMEM_FULL, apply_proposal
 from .philox import philox_proposal_fields
+
+LANES = 128          # lanes of one vreg column: 128 tiles
+SUBLANES = 8         # proposals derived per Philox batch: one full vreg
 
 
 def check_counter_capacity(n_tiles: int, k_per_tile: int) -> None:
@@ -75,33 +102,78 @@ def _pick(vec, lanes, jj):
     return jnp.sum(jnp.where(lanes == jj, vec, jnp.zeros_like(vec)))
 
 
-def _kernel(seed_ref, round_ref, off_ref, dom_ref, dirs_ref, grid_ref,
-            out_ref, *scratch, t_eps: float, t_eps_mu: float, k: int,
-            th: int, tw: int, tiles_w: int, interior: int, nbhd: int,
-            gw: int, band_tiles: int):
-    q, tj, r0, c0 = band_tile(th, tw, tiles_w)
-    # global raster tile id: the program's tile offset by this shard's
-    # first owned tile, flattened against the GLOBAL tile-grid width
-    ti = pl.program_id(0) * (band_tiles // tiles_w) + q
-    tile_id = ((off_ref[0, 0] + ti).astype(jnp.uint32) * jnp.uint32(gw)
-               + (off_ref[0, 1] + tj).astype(jnp.uint32))
+# ------------------------- one round, tiles on lanes ---------------------- #
 
-    def sweep(work):
-        lanes, (cells, dirns, uact, udom) = _tile_proposals(
-            tile_id, round_ref[0, 0], seed_ref[0, 0], seed_ref[0, 1], k=k,
-            interior=interior, nbhd=nbhd)
+def lane_layout(n_tiles: int) -> Tuple[int, int]:
+    """(tiles per lane block, tiles padded to whole blocks) of the
+    one-round kernel. Two 128-lane columns per block let the scheduler
+    overlap one column's serial chain (the reads' reductions, the
+    dominance lookup, the rules) with the other's: on a v5e chip a
+    3200^2 round with its re-layout takes 8.7 ms in place of 10.0, 7/8
+    of the time. They are taken unless padding to 256 lanes, in place of
+    128, makes more than 8/7 as many lanes, which would cost that back."""
+    one, two = (-(-n_tiles // n) * n for n in (LANES, 2 * LANES))
+    return (2 * LANES, two) if 7 * two <= 8 * one else (LANES, one)
 
-        def body(jj, _):
-            apply_tile_proposal(
-                work, dom_ref, dirs_ref, r0, c0, _pick(cells, lanes, jj),
-                _pick(dirns, lanes, jj), _pick(uact, lanes, jj),
-                _pick(udom, lanes, jj), iw=tw - 2, t_eps=t_eps,
-                t_eps_mu=t_eps_mu)
-            return 0
 
-        lax.fori_loop(0, k, body, 0)
+def _round_kernel(seed_ref, round_ref, dirs_ref, table_ref, tile_ref,
+                  cells_ref, out_ref, *, t_eps: float, t_eps_mu: float,
+                  k: int, th: int, tw: int, nbhd: int, n_states: int):
+    rows, lanes = out_ref.shape
+    iw = tw - 2
+    k0, k1 = seed_ref[0, 0], seed_ref[0, 1]
+    round_idx = round_ref[0, 0]
+    ctr0 = tile_ref[...] * jnp.uint32(k)          # (1, lanes): counter of j=0
+    sub = lax.broadcasted_iota(jnp.uint32, (SUBLANES, lanes), 0)
+    row = lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
+    # neighbour offset of each direction, as a row step of the layout
+    offs = [dirs_ref[d, 0] * tw + dirs_ref[d, 1] for d in range(nbhd)]
+    # dominance table: row a * n_states + b holds D[a, b] and D[b, a]
+    entry = lax.broadcasted_iota(jnp.int32, (table_ref.shape[0], lanes), 0)
+    dom_sn = jnp.broadcast_to(table_ref[:, 0:1], entry.shape)
+    dom_ns = jnp.broadcast_to(table_ref[:, 1:2], entry.shape)
 
-    band_program(grid_ref, out_ref, scratch, sweep)
+    def positions(j0):
+        """Rows of cell and neighbour, and the two draws, of proposals
+        j0 .. j0 + 7 of every tile, as (8, lanes) arrays."""
+        j = sub + j0.astype(jnp.uint32)
+        cell, dirn, ua, ud = philox_proposal_fields(
+            ctr0 + j, round_idx, k0, k1, (th - 2) * iw, nbhd)
+        # interior row q = cell // iw by compares: cell lies at tile row
+        # q + 1, column cell - q * iw + 1, i.e. layout row cell + 2q + tw + 1
+        q = sum((cell >= i * iw).astype(jnp.int32) for i in range(1, th - 2))
+        pos = cell + 2 * q + (tw + 1)
+        off = jnp.zeros_like(pos) + offs[0]
+        for d in range(1, nbhd):
+            off = jnp.where(dirn == d, offs[d], off)
+        npos = pos + off
+        if k % SUBLANES:
+            # proposals past K in the last batch point at no row: no-ops
+            pos = jnp.where(j < k, pos, -1)
+            npos = jnp.where(j < k, npos, -1)
+        return pos, npos, ua, ud
+
+    def play(x, pos, npos, ua, ud):
+        """Proposal (pos, npos, ua, ud), (1, lanes) each, on every tile."""
+        at_s = row == pos
+        at_n = row == npos
+        s = jnp.sum(jnp.where(at_s, x, 0), axis=0, keepdims=True)
+        n = jnp.sum(jnp.where(at_n, x, 0), axis=0, keepdims=True)
+        hit = entry == s * n_states + n
+        p1 = jnp.sum(jnp.where(hit, dom_sn, 0.0), axis=0, keepdims=True)
+        p2 = jnp.sum(jnp.where(hit, dom_ns, 0.0), axis=0, keepdims=True)
+        new_s, new_n = pair_update(s, n, ua, ud, p1, p2, t_eps, t_eps_mu)
+        return jnp.where(at_s, new_s, jnp.where(at_n, new_n, x))
+
+    def batch(g, carry):
+        pos, npos, ua, ud = positions(g * SUBLANES)
+        for i in range(SUBLANES):                 # static unroll
+            out_ref[...] = play(out_ref[...], pos[i:i + 1], npos[i:i + 1],
+                                ua[i:i + 1], ud[i:i + 1])
+        return carry
+
+    out_ref[...] = cells_ref[...]
+    lax.fori_loop(0, -(-k // SUBLANES), batch, 0)
 
 
 def escg_tile_round_fused(grid: jax.Array, seed: jax.Array,
@@ -125,27 +197,62 @@ def escg_tile_round_fused(grid: jax.Array, seed: jax.Array,
     h, w = grid.shape
     th, tw = tile_shape
     gh, gw = h // th, w // tw
+    n_tiles = gh * gw
     if grid_tiles_w is None:
         # single-lattice call: the local tile grid IS the global one.
         # Sharded callers pass grid_tiles_w and guard with the true
         # global tile count themselves (core/sharded.py).
-        check_counter_capacity(gh * gw, k_per_tile)
+        check_counter_capacity(n_tiles, k_per_tile)
+    lanes, t_pad = lane_layout(n_tiles)
+
+    # tile-major layout: cells[r * tw + c, t] = grid[ti * th + r, tj * tw + c]
+    cells = (grid.astype(jnp.int32).reshape(gh, th, gw, tw)
+             .transpose(1, 3, 0, 2).reshape(th * tw, n_tiles))
+    cells = jnp.pad(cells, ((0, 0), (0, t_pad - n_tiles)))
+    # global raster tile id of each lane: the local (i, j) position offset
+    # by this shard's first owned tile, flattened against the GLOBAL
+    # tile-grid width (dummy lanes run past the grid; they are dropped)
+    if tile_offset is None:
+        tile_offset = jnp.zeros((2,), jnp.int32)
+    off = jnp.asarray(tile_offset).astype(jnp.int32)
+    ti, tj = np.divmod(np.arange(t_pad, dtype=np.int32), gw)
+    tile_id = ((off[0] + ti).astype(jnp.uint32)
+               * jnp.uint32(gw if grid_tiles_w is None else grid_tiles_w)
+               + (off[1] + tj).astype(jnp.uint32)).reshape(1, t_pad)
+
+    # the dominance table as a (pairs, 2) column pair for one-hot lookups:
+    # row a * (S + 1) + b holds (D[a, b], D[b, a]); rows padded to vregs
+    n_states = dom.shape[0]
+    n_pairs = n_states * n_states
+    table = jnp.stack([dom.reshape(-1), dom.T.reshape(-1)], axis=1)
+    table = jnp.pad(table.astype(jnp.float32),
+                    ((0, -n_pairs % SUBLANES), (0, 0)))
 
     kern = functools.partial(
-        _kernel, t_eps=float(t_eps), t_eps_mu=float(t_eps_mu),
-        k=int(k_per_tile), th=th, tw=tw, tiles_w=gw,
-        interior=(th - 2) * (tw - 2), nbhd=int(neighbourhood),
-        gw=int(gw if grid_tiles_w is None else grid_tiles_w))
-    if tile_offset is None:
-        tile_offset = jnp.zeros((2,), jnp.uint32)
-    call = band_call(kern, grid, tile_shape, [SMEM_FULL] * 5, interpret,
-                     "escg_round_fused")
+        _round_kernel, t_eps=float(t_eps), t_eps_mu=float(t_eps_mu),
+        k=int(k_per_tile), th=th, tw=tw, nbhd=int(neighbourhood),
+        n_states=n_states)
+    block = pl.BlockSpec((th * tw, lanes), lambda b: (0, b))
     # scalar operands stay 2-D: under vmap a batched 1-D operand would get
     # an illegal (1, n) block
-    return call(seed.reshape(1, 2).astype(jnp.uint32),
-                jnp.reshape(round_idx, (1, 1)).astype(jnp.uint32),
-                jnp.reshape(tile_offset, (1, 2)).astype(jnp.int32),
-                dom, dirs, grid)
+    out = pl.pallas_call(
+        kern,
+        grid=(t_pad // lanes,),
+        in_specs=[SMEM_FULL] * 3
+        + [pl.BlockSpec(table.shape, lambda b: (0, 0)),
+           pl.BlockSpec((1, lanes), lambda b: (0, b)), block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((th * tw, t_pad), jnp.int32),
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="escg_round_fused",
+    )(seed.reshape(1, 2).astype(jnp.uint32),
+      jnp.reshape(round_idx, (1, 1)).astype(jnp.uint32),
+      dirs, table, tile_id, cells)
+    return (out[:, :n_tiles].reshape(th, tw, gh, gw).transpose(2, 0, 3, 1)
+            .reshape(h, w).astype(grid.dtype))
 
 
 # ------------------------ multi-MCS megakernel ---------------------------- #

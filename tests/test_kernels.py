@@ -121,18 +121,34 @@ def test_density_kernel(hw, species):
 
 # --------------------------- fused-PRNG kernel ---------------------------- #
 
-@pytest.mark.parametrize("hw,tile,species,nbhd,seed", [
-    ((32, 64), (8, 16), 5, 4, (0xABCD1234, 0x5678DEAD)),
-    ((16, 16), (8, 8), 3, 8, (1, 2)),
-    ((24, 48), (8, 16), 8, 4, (0, 0)),
+@pytest.mark.parametrize("hw,tile,species,nbhd,seed,cell_dtype", [
+    pytest.param((32, 64), (8, 16), 5, 4, (0xABCD1234, 0x5678DEAD), "int32",
+                 id="hw0-tile0-5-4-seed0"),
+    pytest.param((16, 16), (8, 8), 3, 8, (1, 2), "int32",
+                 id="hw1-tile1-3-8-seed1"),
+    pytest.param((24, 48), (8, 16), 8, 4, (0, 0), "int32",
+                 id="hw2-tile2-8-4-seed2"),
+    # 160 tiles: one block of two 128-lane columns, 96 of them dummy
+    pytest.param((64, 320), (8, 16), 3, 4, (0x9E3779B9, 7), "int32",
+                 id="160_tiles_two_columns"),
+    # 512 tiles: two blocks of 256 lanes
+    pytest.param((64, 512), (8, 8), 3, 8, (5, 0xFFFFFFFF), "int32",
+                 id="512_tiles_two_blocks"),
+    # 384 tiles: three blocks of 128 lanes (256-lane padding would cost more)
+    pytest.param((48, 512), (8, 8), 5, 4, (11, 12), "int32",
+                 id="384_tiles_three_blocks"),
+    pytest.param((32, 64), (8, 16), 5, 4, (0xABCD1234, 0x5678DEAD), "int8",
+                 id="int8"),
 ])
 def test_escg_fused_kernel_matches_host_philox_oracle(hw, tile, species,
-                                                      nbhd, seed):
+                                                      nbhd, seed, cell_dtype):
     """In-kernel Philox proposal derivation == host-side derivation feeding
-    the standard tile oracle (bit-exact)."""
+    the standard tile oracle (bit-exact). K = 61 is not a multiple of the
+    kernel's batch of eight proposals."""
     h, w = hw
     th, tw = tile
-    grid = init_grid(jax.random.PRNGKey(h + species), h, w, species, 0.1)
+    grid = init_grid(jax.random.PRNGKey(h + species), h, w, species, 0.1,
+                     dtype=jnp.dtype(cell_dtype))
     offs = (1, 2) if species >= 5 else (1,)
     dom = jnp.asarray(dm.circulant(species, offs))
     nt = (h // th) * (w // tw)
@@ -148,7 +164,18 @@ def test_escg_fused_kernel_matches_host_philox_oracle(hw, tile, species,
                                    jnp.asarray(dirn), jnp.asarray(ua),
                                    jnp.asarray(ud), dom, tile, 0.25, 0.6)
     want = jnp.roll(want, (3, 5), (0, 1))
+    assert got.dtype == grid.dtype
     assert jnp.array_equal(got, want)
+
+
+def test_fused_lane_layout():
+    """Two 128-lane columns per block unless their padding makes more
+    than 8/7 as many lanes; the padded count is whole blocks."""
+    from repro.kernels.escg_update_fused import lane_layout
+    assert lane_layout(4) == (128, 128)
+    assert lane_layout(160) == (256, 256)
+    assert lane_layout(384) == (128, 384)
+    assert lane_layout(40_000) == (256, 40_192)
 
 
 @pytest.mark.parametrize("hw,tile,species,nbhd,k_steps", [

@@ -118,6 +118,28 @@ def test_fused_kernel_compiles_at_3200(one_chip, no_compile_cache,
         _dom(one_chip), kernel="escg_round_fused")
 
 
+def test_fused_kernel_compiles_at_3200_at_its_widest(one_chip,
+                                                      no_compile_cache):
+    """Eight neighbours and a 9 x 9 dominance table (8 species): the
+    kernel's direction select chain and dominance lookup at their
+    largest, over the 3200x3200 lattice's lane blocks."""
+    _, k = _setup(L, L)
+    species = 8
+
+    def round_(grid, seed, dom):
+        return escg_update_fused.escg_tile_round_fused(
+            grid, seed, jnp.uint32(0), dom, jnp.asarray(DIRS, jnp.int32),
+            TILE, k, 0.25, 0.6, 8, interpret=False)
+
+    _compile_for_chip(
+        round_,
+        jax.ShapeDtypeStruct((L, L), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((species + 1, species + 1), jnp.float32,
+                             sharding=one_chip),
+        kernel="escg_round_fused")
+
+
 def test_fused_kernel_compiles_for_a_trial_batch(one_chip,
                                                  no_compile_cache):
     """The trial drivers vmap the kernel: every operand gains a batch
