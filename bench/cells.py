@@ -1,14 +1,26 @@
-"""Cells of the benchmark, found by name.
+"""Cells of the benchmark, and the files that make them, found by name.
 
 ``BENCHMARK.json`` at the root names each cell's configuration and
-traffic. A configuration is ``bench/configs/<config>.json``: the study
-deployment's sizes and physics, stated in full so that the plain
-reference can follow it without the program. A traffic mix is
-``bench/traffic/<traffic>.json``: which engine runs the deployment and
-how the run is driven. Adding a cell adds files; no file here changes.
+traffic. A cell is made of files, each found by a name, in the checkout
+at ``root`` first and else in this package, so that a new cell, even one
+of a new shape, adds files and edits none:
+
+- a configuration, ``bench/configs/<config>.json``: the study
+  deployment's sizes and physics, stated in full so that the plain
+  reference can follow it without the program, and the name of its
+  driver;
+- a traffic mix, ``bench/traffic/<traffic>.json``: which engine runs the
+  deployment and how much work the window holds;
+- a driver, ``bench/drivers/<driver>.py``: how the program is called, the
+  plain reference of that call, and the comparison (``bench.drivers``);
+- an engine's reference proposal streams,
+  ``bench/references/<engine>.py`` (``bench.references``);
+- a per-layer metric's reader, ``bench/metrics/<metric>.py``
+  (``bench.harness``).
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import math
 import os
@@ -28,6 +40,7 @@ class Cell:
     config: dict
     traffic: dict
     benchmark: dict       # the whole of BENCHMARK.json
+    root: str = DEFAULT_ROOT   # the checkout whose files make the cell
 
     @property
     def chunk_mcs(self) -> int:
@@ -78,4 +91,26 @@ def load(name: str, root: Optional[str] = None) -> Cell:
     traffic = _load(os.path.join(root, DATA_DIR, "traffic",
                                  f"{spec['traffic']}.json"))
     return Cell(name=name, spec=spec, config=config, traffic=traffic,
-                benchmark=bench)
+                benchmark=bench, root=root)
+
+
+def find_module(kind: str, name: str, root: Optional[str] = None):
+    """The module ``bench/<kind>/<name>.py`` of the checkout at ``root``,
+    else of this package. An unknown name raises a ``KeyError`` that
+    names the modules there are."""
+    dirs = [os.path.join(root or DEFAULT_ROOT, DATA_DIR, kind),
+            os.path.join(BENCH_DIR, kind)]
+    for d in dirs:
+        path = os.path.join(d, f"{name}.py")
+        if os.path.exists(path):
+            break
+    else:
+        known = sorted({f[:-3] for d in dirs if os.path.isdir(d)
+                        for f in os.listdir(d)
+                        if f.endswith(".py") and not f.startswith("_")})
+        raise KeyError(f"no {kind} module {name!r}; known: {known}")
+    spec = importlib.util.spec_from_file_location(
+        f"{__package__}.{kind}.{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -1,31 +1,29 @@
-"""The comparison that decides ``correct``: what the timed call produced,
-against the plain reference (``bench.references``) run from the same seed
-after the window.
+"""The comparisons that decide ``correct``, shared by the drivers.
 
-Every number compared is a count of answers that differ, and every limit
-is 0: the engines are exact integer simulations whose random streams are
+A cell's driver (``bench.drivers``) follows the run with the plain
+reference (``bench.references``) from the same seed after the window,
+compares what the timed call produced with it by the functions here, and
+holds the limit of each number compared. Every number compared is a
+count of answers that differ, and every limit of the drivers here is 0:
+the engines are exact integer simulations whose random streams are
 functions of the seed, so a sound run reproduces the reference cell for
 cell.
 
-- Trial batches (``run_trials``): a sample of trials drawn from the seed.
-  For each, the final species counts (``TrialResult.densities``), the
+- Trial batches: a sample of trials drawn from the seed
+  (``sample_trials``). For each, the final species counts, the
   alive-species count the driver streamed to the hook after every chunk,
-  and the per-trial extinction MCS, stasis MCS and survival.
-- Single lattices (``simulate``): the final lattice cell for cell, and
-  every step's species counts, as the result's density stream holds them
-  and as the driver streamed them to the hook after every chunk; and,
-  where the configuration streams it, every step's interface length, as
-  the unlike-bond count that the share carries (share × 2 N, to the
-  nearest bond) against the reference's count.
+  and the per-trial extinction MCS, stasis MCS and survival
+  (``check_trials``).
+- Single lattices: the final lattice cell for cell, and every step's
+  species counts, as the result's density stream holds them and as the
+  driver streamed them to the hook after every chunk; and, where the
+  configuration streams it, every step's interface length, as the
+  unlike-bond count that the share carries (share × 2 N, to the nearest
+  bond) against the reference's count (``check_single``).
 """
 from __future__ import annotations
 
 import numpy as np
-
-from . import references
-
-LIMITS = {"trials_differing": 0, "stream_rows_differing": 0,
-          "cells_differing": 0}
 
 
 def sample_trials(n_trials: int, sample: int, seed: int) -> np.ndarray:
@@ -118,49 +116,21 @@ def check_single(got: dict, ref_counts: np.ndarray, ref_bonds: np.ndarray,
             "cells_differing": cells}
 
 
-def run_reference(cell, seed: int, n_mcs: int, dtype=None):
-    """The reference's answers for this run: ``(sampled trial ids, their
-    counts)`` for a trial batch, ``(counts, unlike bonds, final
-    lattice)`` for a single lattice."""
-    import jax.numpy as jnp  # noqa: PLC0415
-
-    dtype = jnp.float32 if dtype is None else dtype
-    cfg, engine = cell.config, cell.traffic["engine"]
-    if cfg["driver"] == "run_trials":
-        ids = sample_trials(cfg["trials"], cell.traffic["check_sample"],
-                            seed)
-        return ids, references.trials(cfg, engine, seed, ids, n_mcs, dtype)
-    return references.single(cfg, engine, seed, n_mcs, dtype)
+def trials_as_answers(counts: np.ndarray, ids: np.ndarray, n_trials: int,
+                      chunk_mcs: int) -> dict:
+    """Reference counts of the trials ``ids`` dressed as a trial batch's
+    answers over all ``n_trials`` (the others left 0)."""
+    got = {}
+    for key, w in trial_answers(counts, chunk_mcs).items():
+        full = np.zeros((n_trials,) + w.shape[1:], w.dtype)
+        full[ids] = w
+        got[key] = full
+    return got
 
 
-def check(cell, got: dict, seed: int, n_mcs: int, ref=None) -> dict:
-    """``{name: value}`` of every number compared for this cell; ``ref``
-    stands in for the reference's answers (the control passes its own)."""
-    if ref is None:
-        ref = run_reference(cell, seed, n_mcs)
-    if cell.config["driver"] == "run_trials":
-        ids, counts = ref
-        return check_trials(got, counts, ids, cell.chunk_mcs)
-    counts, bonds, grid = ref
-    return check_single(got, counts, bonds, grid,
-                        cell.config.get("observables", ()))
-
-
-def reference_as_answers(cell, ref) -> dict:
-    """Reference output dressed as the timed call's answers, so that one
-    reference (the control, computed lower) can stand in the program's
-    place in ``check``."""
-    if cell.config["driver"] == "run_trials":
-        ids, counts = ref
-        want = trial_answers(counts, cell.chunk_mcs)
-        n = cell.config["trials"]
-        got = {}
-        for key, w in want.items():
-            full = np.zeros((n,) + w.shape[1:], w.dtype)
-            full[ids] = w
-            got[key] = full
-        return got
-    counts, bonds, grid = ref
+def single_as_answers(counts: np.ndarray, bonds: np.ndarray,
+                      grid: np.ndarray) -> dict:
+    """A single lattice's reference dressed as its run's answers."""
     return {"counts": counts[1:], "hook_counts": counts[1:],
             "initial_counts": counts[0], "grid": grid,
             "interface_length": interface_share(bonds, grid.size)}

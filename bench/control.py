@@ -24,14 +24,14 @@ sys.path[0:1] = [ROOT, os.path.join(ROOT, "src")]  # not bench/ itself
 
 def control_numbers(cell, seed: int, n_mcs: int) -> dict:
     """The cell's compared numbers with the lower-precision reference in
-    the program's place."""
+    the program's place, through the cell's driver."""
     import jax.numpy as jnp  # noqa: PLC0415
-    from bench import compare  # noqa: PLC0415
+    from bench import drivers  # noqa: PLC0415
 
-    sound = compare.run_reference(cell, seed, n_mcs)
-    low = compare.run_reference(cell, seed, n_mcs, dtype=jnp.bfloat16)
-    return compare.check(cell, compare.reference_as_answers(cell, low),
-                         seed, n_mcs, ref=sound)
+    driver = drivers.of(cell)
+    sound = driver.reference(cell, seed, n_mcs, jnp.float32)
+    low = driver.reference(cell, seed, n_mcs, jnp.bfloat16)
+    return driver.check(cell, driver.as_answers(cell, low), sound)
 
 
 def main(argv=None) -> int:
@@ -42,9 +42,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax  # noqa: PLC0415
-    from bench import cells, compare  # noqa: PLC0415
+    from bench import cells, drivers  # noqa: PLC0415
 
     cell = cells.load(args.workload)
+    limits = drivers.of(cell).LIMITS
     n_mcs = (1 + cell.window_chunks(args.seconds)) * cell.chunk_mcs
     dev = jax.devices()[0]
     for seed in args.seeds:
@@ -54,7 +55,7 @@ def main(argv=None) -> int:
             "workload": args.workload, "seed": seed, "mcs": n_mcs,
             "device": {"platform": dev.platform, "kind": dev.device_kind},
             "seconds": time.perf_counter() - t0,
-            "check": {k: {"value": v, "limit": compare.LIMITS[k]}
+            "check": {k: {"value": v, "limit": limits[k]}
                       for k, v in numbers.items()}}), flush=True)
     return 0
 

@@ -2,29 +2,33 @@
 
 The run:
 
-1. finds the cell's configuration and traffic by name (``bench.cells``);
+1. finds the cell's files by name (``bench.cells``): its configuration,
+   its traffic, and the driver that the configuration names
+   (``bench.drivers``);
 2. places JAX's persistent compilation cache inside the checkout, and
    refuses to go on unless JAX sees as many TPU chips as the cell asks
    for: there is no fallback;
-3. calls the cell's driver once (``bench.drivers``) for a warm-up chunk
-   and then the window, a fixed number of equal chunks sized from
-   ``--seconds`` (``Cell.window_chunks``). Set-up runs from process start
-   to the end of the warm-up chunk: JAX and TPU start-up, tracing,
-   compiling or reading the cache, the initial lattices, the warm-up
-   chunk. The window runs from there to the last chunk boundary;
-4. reads the device's peak memory, then runs the plain reference from
-   the same seed and compares (``bench.compare``);
+3. calls the driver once for a warm-up chunk and then the window, a
+   fixed number of equal chunks sized from ``--seconds``
+   (``Cell.window_chunks``). Set-up runs from process start to the end
+   of the warm-up chunk: JAX and TPU start-up, tracing, compiling or
+   reading the cache, the initial lattices, the warm-up chunk. The
+   window runs from there to the last chunk boundary;
+4. reads the device's peak memory, then has the driver run its plain
+   reference from the same seed and compare;
 5. prints each number compared beside its limit as the last lines of
    standard error, and the result as the last line of standard output.
 
 With ``--trace 1`` the window runs under the profiler, and the per-layer
 metrics are read from the trace by readers found by name
 (``bench/metrics/<metric>.py``).
+
+Nothing here depends on a cell's shape: a new cell adds its
+configuration, traffic, readers, engine streams and driver as files.
 """
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import os
 import shutil
@@ -34,7 +38,7 @@ import traceback
 from collections import defaultdict
 from types import SimpleNamespace
 
-from . import cells, compare, trace_reduce
+from . import cells, drivers, trace_reduce
 
 # jax.monitoring duration events, by the part of set-up they time. The
 # backend-compile event wraps the whole compile-or-read-the-cache call, so
@@ -116,14 +120,7 @@ def _reader(name: str, root: str):
     """``read(ctx)`` of the per-layer metric ``name``: the file
     ``bench/metrics/<name>.py`` of the checkout at ``root``, else of this
     package."""
-    path = os.path.join(root, cells.DATA_DIR, "metrics", f"{name}.py")
-    if not os.path.exists(path):
-        path = os.path.join(cells.BENCH_DIR, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return cells.find_module("metrics", name, root).read
 
 
 def _device_info(jax):
@@ -141,7 +138,8 @@ def main(argv=None, *, t_start=None, root=None,
     t_start = time.perf_counter() if t_start is None else t_start
     args = parse(argv)
     cell = cells.load(args.workload, root)
-    root = root or cells.DEFAULT_ROOT
+    root = cell.root
+    driver = drivers.of(cell)
 
     import jax  # noqa: PLC0415
     from repro.launch.compile_cache import enable_compile_cache  # noqa
@@ -179,9 +177,8 @@ def main(argv=None, *, t_start=None, root=None,
             spans.end(trace_reduce.WINDOW_SPAN)
             jax.profiler.stop_trace()
 
-    from . import drivers  # noqa: PLC0415
     try:
-        boundaries, got = drivers.run(cell, args.seed, n_mcs, on_boundary)
+        boundaries, got = driver.run(cell, args.seed, n_mcs, on_boundary)
     finally:
         jax.monitoring.unregister_event_duration_listener(monitor)
     device = _device_info(jax)
@@ -196,18 +193,14 @@ def main(argv=None, *, t_start=None, root=None,
     window_compiles = monitor.compiles_between(w0, w1)
 
     t_ref = time.perf_counter()
-    numbers = compare.check(cell, got, args.seed, n_mcs)
+    numbers = driver.check(cell, got,
+                           driver.reference(cell, args.seed, n_mcs))
     ref_s = time.perf_counter() - t_ref
+    limits = driver.LIMITS
     incomplete = got["mcs_completed"] != n_mcs
     correct = not incomplete and all(
-        v <= compare.LIMITS[k] for k, v in numbers.items())
-    if cell.config["driver"] == "run_trials":   # answers: sampled trials
-        attempted = min(cell.traffic["check_sample"], cell.config["trials"])
-        failed = numbers["trials_differing"]
-    else:                  # answers: each step's counts, the final lattice
-        attempted = n_mcs + 1
-        failed = (numbers["stream_rows_differing"]
-                  + (numbers["cells_differing"] > 0))
+        v <= limits[k] for k, v in numbers.items())
+    attempted, failed = driver.answered(cell, got, numbers, n_mcs)
 
     metrics, breakdown = {}, None
     if args.trace:
@@ -246,14 +239,14 @@ def main(argv=None, *, t_start=None, root=None,
                    "updates": window_updates, "seconds": window_s,
                    "compiles": window_compiles},
         "setup_split_s": setup, "reference_s": ref_s,
-        "check": {k: {"value": v, "limit": compare.LIMITS[k]}
+        "check": {k: {"value": v, "limit": limits[k]}
                   for k, v in numbers.items()},
     })
     if incomplete:
         print(f"bench: the call ran {got['mcs_completed']} of {n_mcs} MCS",
               file=sys.stderr)
     for k, v in numbers.items():
-        print(f"check {k} = {v} (limit {compare.LIMITS[k]})",
+        print(f"check {k} = {v} (limit {limits[k]})",
               file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result), flush=True)

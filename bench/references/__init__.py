@@ -1,33 +1,35 @@
-"""Plain references of the two drivers' semantics, over any engine whose
-proposal streams have a module here (``references/<engine>.py``, found by
-the engine's name).
+"""Plain references of a lattice run and of an IID trial batch, over any
+engine whose proposal streams have a module (``references/<engine>.py``,
+found by the engine's name in the checkout at ``root`` first, else here).
 
 ``single`` follows one lattice from the seed the way a single-lattice run
 does; ``trials`` follows chosen trials of an IID batch the way the trial
 driver seeds them. Both return the species counts after every
 Monte-Carlo step, and ``single`` the unlike-bond count after every step
-and the final lattice, for the comparison in ``bench.compare``.
+and the final lattice. The drivers (``bench.drivers``) build their references on these.
 """
 from __future__ import annotations
 
-import importlib
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import cells
+
 from . import escg
 
 
-def streams(engine: str):
+def streams(engine: str, root: Optional[str] = None):
     """The module holding ``engine``'s proposal streams."""
-    return importlib.import_module(f"{__name__}.{engine}")
+    return cells.find_module("references", engine, root)
 
 
-def _stepper(cfg: dict, engine: str, dtype):
+def _stepper(cfg: dict, engine: str, dtype, root):
     t_mig, t_int = escg.thresholds(cfg)
     dom = escg.dominance_matrix(cfg)
-    mod = streams(engine)
+    mod = streams(engine, root)
 
     def step(grid, key):
         grid = mod.step(grid, key, cfg, t_mig=t_mig, t_int=t_int, dom=dom,
@@ -42,13 +44,13 @@ def _init(cfg: dict, key):
 
 
 def single(cfg: dict, engine: str, seed: int, n_mcs: int,
-           dtype=jnp.float32):
+           dtype=jnp.float32, root: Optional[str] = None):
     """One lattice: the run key is ``PRNGKey(seed)``, split once for the
     initial lattice, then once per step for the step key. Returns
     ``(counts (n_mcs + 1, S + 1), unlike bonds (n_mcs,), final
     lattice)``; row 0 of the counts is the initial lattice, and the
     unlike-bond count is taken after every step."""
-    inner = _stepper(cfg, engine, dtype)
+    inner = _stepper(cfg, engine, dtype, root)
 
     @jax.jit
     def step(grid, key):
@@ -68,12 +70,13 @@ def single(cfg: dict, engine: str, seed: int, n_mcs: int,
 
 
 def trials(cfg: dict, engine: str, seed: int, trial_ids, n_mcs: int,
-           dtype=jnp.float32):
+           dtype=jnp.float32, root: Optional[str] = None):
     """Trials ``trial_ids`` of a batch: trial t's key is ``fold_in(
     PRNGKey(seed), t)``, split into the initial-lattice key and the run
     key, which is split once per step. Returns counts (len(trial_ids),
-    n_mcs + 1, S + 1)."""
-    step = jax.jit(jax.vmap(_stepper(cfg, engine, dtype)))
+    n_mcs + 1, S + 1). A driver whose trials play several physics points
+    calls this once per point, on that point's ids."""
+    step = jax.jit(jax.vmap(_stepper(cfg, engine, dtype, root)))
     base = jax.random.PRNGKey(seed)
 
     def start(t):

@@ -4,7 +4,7 @@ each cell's path at test size. The same control at the cells' own sizes
 runs on the chip through ``bench/control.py``."""
 import pytest
 
-from bench import compare
+from bench import drivers
 from bench.cells import Cell
 from bench.control import control_numbers
 
@@ -18,7 +18,8 @@ def test_control_fails_the_trial_batch(seed):
                 traffic={"engine": "sublattice", "check_sample": 16},
                 benchmark={})
     numbers = control_numbers(cell, seed, 4)
-    assert numbers["trials_differing"] > compare.LIMITS["trials_differing"]
+    limits = drivers.find("run_trials").LIMITS
+    assert numbers["trials_differing"] > limits["trials_differing"]
 
 
 @pytest.mark.parametrize("engine", ["pallas_fused", "sublattice"])
@@ -27,6 +28,7 @@ def test_control_fails_the_lattice(engine):
                 config=small_config(LATTICE, height=64, length=128),
                 traffic={"engine": engine}, benchmark={})
     numbers = control_numbers(cell, 2**31 + 3, 4)
-    assert numbers["cells_differing"] > compare.LIMITS["cells_differing"]
+    limits = drivers.find("simulate").LIMITS
+    assert numbers["cells_differing"] > limits["cells_differing"]
     assert numbers["stream_rows_differing"] > \
-        compare.LIMITS["stream_rows_differing"]
+        limits["stream_rows_differing"]
