@@ -8,9 +8,16 @@ README engine matrix all follow automatically.
 
 Engine contract: ``build(params, dom) -> BuiltEngine`` where
 ``one_mcs(grid, key) -> (grid, kept, attempts)`` advances one Monte-Carlo
-step (N elementary updates) fully on-device. ``grid_sharding`` is non-None
+step (N elementary updates) fully on-device. Engines whose caps set
+``physics_operand`` also take ``one_mcs(grid, key, point)``: the physics
+point (:class:`Physics`: the dominance matrix and the two action
+thresholds) as device operands, so that one compiled program runs a
+batch of trials each with its own point (a ``run_trials`` sweep,
+DESIGN.md §4). Without ``point`` they run the point they were built
+with, as every single-point call does. ``grid_sharding`` is non-None
 for multi-device engines: the driver ``device_put``s the lattice onto it
-before the first chunk and every array op thereafter stays device-resident.
+before the first chunk and every array op thereafter stays
+device-resident.
 """
 from __future__ import annotations
 
@@ -29,6 +36,32 @@ from .rng import proposal_batch, round_shift, tile_stream_batch
 
 if TYPE_CHECKING:  # avoid a runtime cycle: params validates via this module
     from .params import EscgParams
+
+
+class Physics(NamedTuple):
+    """One physics point as device operands: the padded (S+1, S+1)
+    float32 dominance matrix and the float32 action thresholds
+    (``EscgParams.action_thresholds``). A batch of points stacks each
+    field on a leading axis."""
+    dom: jax.Array
+    t_eps: jax.Array
+    t_eps_mu: jax.Array
+
+
+def physics(p: "EscgParams", dom) -> Physics:
+    """The physics point of ``p`` with dominance matrix ``dom``."""
+    t_eps, t_eps_mu = p.action_thresholds()
+    return Physics(jnp.asarray(dom, jnp.float32), jnp.float32(t_eps),
+                   jnp.float32(t_eps_mu))
+
+
+def stepper(one_mcs: Callable, point: Optional[Physics]) -> Callable:
+    """``one_mcs(grid, key)`` with ``point`` bound, where there is one
+    (None: the engine's own point, the only form of engines without
+    ``EngineCaps.physics_operand``)."""
+    if point is None:
+        return one_mcs
+    return lambda grid, key: one_mcs(grid, key, point)
 
 
 class BuiltEngine(NamedTuple):
@@ -119,6 +152,11 @@ class EngineCaps:
                                # a different bit-identity family (e.g.
                                # 'fused' -> 'pallas_fused'); resolve via
                                # oracle_for()
+    physics_operand: bool = False  # one_mcs also takes the physics
+                               # point (Physics) as an operand, so a trial
+                               # batch may mix points (trials.run_trials
+                               # over several scenarios). Engines without
+                               # it compile their rates in.
     description: str = ""
     paper: str = ""            # paper algorithm / figure it reproduces
 
@@ -368,13 +406,15 @@ def multi_round_inputs(key: jax.Array, th: int, tw: int, k_steps: int):
 
 
 @register("reference", EngineCaps(
+    physics_operand=True,
     description="sequential oracle; one proposal at a time via lax.scan",
     paper="Algorithm 3.2/3.3 (single-threaded baseline)"))
 def _build_reference(p: "EscgParams", dom: jax.Array) -> BuiltEngine:
-    t_eps, t_eps_mu = p.action_thresholds()
+    built_point = physics(p, dom)
     n = p.n_cells
 
-    def one_mcs(grid, key):
+    def one_mcs(grid, key, point=None):
+        dom, t_eps, t_eps_mu = built_point if point is None else point
         batch = proposal_batch(key, n, n, p.neighbourhood)
         grid, kept = reference_mod.run_proposals(
             grid, batch, t_eps, t_eps_mu, dom, p.flux)
@@ -383,15 +423,18 @@ def _build_reference(p: "EscgParams", dom: jax.Array) -> BuiltEngine:
 
 
 @register("batched", EngineCaps(
+    physics_operand=True,
     description="scatter-min conflict arbitration over proposal sub-batches",
     paper="Algorithm 3.5/3.6 (CUDA port, E2)"))
 def _build_batched(p: "EscgParams", dom: jax.Array) -> BuiltEngine:
-    t_eps, t_eps_mu = p.action_thresholds()
+    built_point = physics(p, dom)
     n = p.n_cells
     n_sub = _pick_sub_batches(n)
     b_sub = n // n_sub
 
-    def one_mcs(grid, key):
+    def one_mcs(grid, key, point=None):
+        dom, t_eps, t_eps_mu = built_point if point is None else point
+
         def body(carry, k):
             g, kept = carry
             batch = proposal_batch(k, b_sub, n, p.neighbourhood)
@@ -404,8 +447,9 @@ def _build_batched(p: "EscgParams", dom: jax.Array) -> BuiltEngine:
     return BuiltEngine(one_mcs)
 
 
-def _build_tiled(p: "EscgParams", dom: jax.Array, run_round) -> BuiltEngine:
-    """Shared builder for the shifted-window engines (jnp and Pallas).
+def _tiled_one_mcs(p: "EscgParams", run_round):
+    """``one_mcs(grid, key, *point)`` of the shifted-window engines (jnp
+    and Pallas), around ``run_round(grid, props, shift, *point)``.
 
     Proposals come from per-tile counter-based streams (tile_stream_batch),
     so the trajectory is a function of (key, tile id) only — the sharded
@@ -420,26 +464,29 @@ def _build_tiled(p: "EscgParams", dom: jax.Array, run_round) -> BuiltEngine:
     th, tw, n_tiles, k_per_tile, interior = _tiled_setup(p)
     tile_ids = jnp.arange(n_tiles, dtype=jnp.int32)
 
-    def one_mcs(grid, key):
+    def one_mcs(grid, key, *point):
         kp, ks = jax.random.split(key)
         props = tile_stream_batch(kp, tile_ids, k_per_tile, interior,
                                   p.neighbourhood)
         shift = round_shift(ks, th, tw)
-        grid = run_round(grid, props, shift, dom=dom)
+        grid = run_round(grid, props, shift, *point)
         attempts = jnp.int32(n_tiles * k_per_tile)
         return grid, attempts, attempts
-    return BuiltEngine(one_mcs)
+    return one_mcs
 
 
 @register("sublattice", EngineCaps(
-    flux_only=True, tiled=True,
+    flux_only=True, tiled=True, physics_operand=True,
     description="shifted-window synchronous sublattice, pure jnp (E3)",
     paper="maxStep §4.2.4 redesigned for tiles (Fig 4.3)"))
 def _build_sublattice(p: "EscgParams", dom: jax.Array) -> BuiltEngine:
-    t_eps, t_eps_mu = p.action_thresholds()
-    run_round = partial(sublattice_mod.run_round, tile_shape=p.tile,
-                        t_eps=t_eps, t_eps_mu=t_eps_mu, roll_back=False)
-    return _build_tiled(p, dom, run_round)
+    built_point = physics(p, dom)
+
+    def run_round(grid, props, shift, point=None):
+        dom, t_eps, t_eps_mu = built_point if point is None else point
+        return sublattice_mod.run_round(grid, props, shift, p.tile, t_eps,
+                                        t_eps_mu, dom, roll_back=False)
+    return BuiltEngine(_tiled_one_mcs(p, run_round))
 
 
 @register("pallas", EngineCaps(
@@ -449,9 +496,9 @@ def _build_sublattice(p: "EscgParams", dom: jax.Array) -> BuiltEngine:
 def _build_pallas(p: "EscgParams", dom: jax.Array) -> BuiltEngine:
     from ..kernels import ops as kernel_ops  # lazy: avoid cycles
     t_eps, t_eps_mu = p.action_thresholds()
-    run_round = partial(kernel_ops.escg_round, tile_shape=p.tile,
+    run_round = partial(kernel_ops.escg_round, dom=dom, tile_shape=p.tile,
                         t_eps=t_eps, t_eps_mu=t_eps_mu, roll_back=False)
-    return _build_tiled(p, dom, run_round)
+    return BuiltEngine(_tiled_one_mcs(p, run_round))
 
 
 @register("pallas_fused", EngineCaps(

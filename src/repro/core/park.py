@@ -10,7 +10,7 @@ read.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -57,14 +57,39 @@ def survival_probabilities(alpha: float, beta: float, gamma: float = 1.0,
     (trials.run_trials); stasis early-exit is safe here because a species
     can never re-appear after stasis, so the survival mask is frozen from
     that point on."""
-    sc = park_scenario(alpha, beta, gamma, mobility)
-    res = run_trials(sc, None, n_trials, key=key,
+    return survival_probabilities_sweep(
+        [(alpha, beta)], gamma, L=L, n_trials=n_trials, mcs=mcs,
+        mobility=mobility, key=key, engine=engine,
+        trial_devices=trial_devices)[0]
+
+
+def survival_probabilities_sweep(points: Sequence[Tuple[float, float]],
+                                 gamma: float = 1.0, L: int = 100,
+                                 n_trials: int = 20,
+                                 mcs: Optional[int] = None,
+                                 mobility: float = 0.0,
+                                 key: Optional[jax.Array] = None,
+                                 engine: str = "batched",
+                                 trial_devices: Optional[int] = None
+                                 ) -> List[Tuple[np.ndarray, np.ndarray]]:
+    """:func:`survival_probabilities` at every (alpha, beta) of ``points``,
+    as ONE trial batch of ``len(points) x n_trials`` lattices: one chunk
+    program for the whole grid of a figure, each point's rates operands
+    of the engine (``trials.run_trials`` over several scenarios; for more
+    than one point the engine must take its physics as an operand, as
+    ``batched``, ``reference`` and ``sublattice`` do). Point p's numbers
+    are those of ``survival_probabilities(*points[p], ...)`` with the
+    same key: trial i of every point starts from the same lattice and
+    random streams. Returns one (survival probability [8], n-survivors
+    histogram [9]) per point, in order."""
+    scs = [park_scenario(a, b, gamma, mobility) for a, b in points]
+    res = run_trials(scs, None, n_trials, key=key,
                      trial_devices=trial_devices,
                      engine_config=EngineConfig(engine=engine),
                      run_config=RunConfig(
                          length=L, height=L,
                          mcs=int(mcs if mcs is not None else L * L)))
-    return res.survival_probabilities(), res.survivors_hist()
+    return [(r.survival_probabilities(), r.survivors_hist()) for r in res]
 
 
 def species5_extinction_std(L_values, mcs_values, alpha: float = 0.15,

@@ -34,19 +34,19 @@ import inspect
 import json
 import re
 from dataclasses import dataclass, field
-from typing import Callable, Dict, Mapping, Optional, Tuple, Union
+from typing import Callable, Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from . import dominance as dom_mod
-from .engines import get_engine
+from .engines import get_engine, physics
 from .params import EscgParams, parse_observables
 
 __all__ = [
     "Scenario", "ScenarioCaps", "ScenarioSpec", "EngineConfig", "RunConfig",
     "register_scenario", "scenario_names", "scenario_specs", "get_scenario",
     "make_scenario", "scenario_key", "compose", "decompose",
-    "resolve_config",
+    "resolve_config", "resolve_points", "POINT_FIELDS",
     "scenario_from_cli", "engine_config_from_args", "run_config_from_args",
     "SCENARIO_CLI_FIELDS",
 ]
@@ -424,6 +424,46 @@ def resolve_config(params: Union[EscgParams, Scenario],
             "engine_config/run_config only apply when the first argument "
             "is a Scenario; an EscgParams already carries both layers")
     return params, dom
+
+
+# the fields in which the points of one trial batch may differ: the
+# action rates, besides the dominance network each point's scenario derives
+POINT_FIELDS = ("mobility", "mu", "sigma", "epsilon")
+
+
+def resolve_points(scenarios: Sequence[Union[EscgParams, Scenario]],
+                   dom: Optional[np.ndarray] = None,
+                   engine_config: Optional[EngineConfig] = None,
+                   run_config: Optional[RunConfig] = None):
+    """``(EscgParams, [Physics, ...])`` of a sweep: each scenario composed
+    with the shared engine and run configs by :func:`resolve_config`
+    (the dominance network ``dom`` for every point, else each scenario's
+    own from the registry), then checked to share every field that sets
+    the shape of the batch: species, neighbourhood, boundary, empty
+    fraction, sizes, dtype, engine and the rest, all but
+    ``POINT_FIELDS``. A mismatch raises a ``ValueError`` naming the
+    field. The params returned are the first point's; the list holds one
+    ``engines.Physics`` per scenario, in order."""
+    if not scenarios:
+        raise ValueError("a sweep needs at least one scenario")
+    resolved = []
+    for sc in scenarios:
+        params, dom_p = resolve_config(sc, dom, engine_config, run_config)
+        params = params.validate()
+        if dom_p is None:
+            dom_p = dom_mod.circulant(params.species)
+        resolved.append((params, dom_p))
+    first = resolved[0][0]
+    blank = dict.fromkeys(POINT_FIELDS)
+    for params, _ in resolved[1:]:
+        a, b = first.replace(**blank), params.replace(**blank)
+        for f in dataclasses.fields(EscgParams):
+            if getattr(a, f.name) != getattr(b, f.name):
+                raise ValueError(
+                    f"the points of a sweep must share every field that "
+                    f"sets the batch's shape; they differ in {f.name!r}: "
+                    f"{getattr(a, f.name)!r} and {getattr(b, f.name)!r}")
+    return first, [physics(params, dom) for params, dom in resolved]
 
 
 def scenario_key(scenario: Scenario) -> str:

@@ -343,6 +343,7 @@ def simulate(params: EscgParams,
         ring, pos = obs_mod.ring_init(cap, (pipe.width,))
     else:
         chunk_fn = build_chunk_fn(p, dom_j, built=eng)
+    chunk_fn = loop.counted(chunk_fn)
     hist = [np.asarray(metrics.counts(grid, p.species))]
     mcs_done, stasis_mcs = 0, -1
     kept_total, att_total = 0, 0
@@ -351,10 +352,11 @@ def simulate(params: EscgParams,
         n_mcs = min(p.chunk_mcs, p.mcs - mcs_done)
         with loop.span(tracing.DISPATCH):
             if obs_on:
-                grid, key, ring, pos, kept, att = chunk_fn(grid, key, ring,
-                                                           pos, n_mcs)
+                grid, key, ring, pos, kept, att = chunk_fn(
+                    grid, key, ring, pos, n_mcs=n_mcs)
             else:
-                grid, key, cnts, kept, att = chunk_fn(grid, key, n_mcs)
+                grid, key, cnts, kept, att = chunk_fn(
+                    grid, key, n_mcs=n_mcs)
         # the flushed ring rows carry every per-MCS statistic, counts
         # included: no separate per-MCS counts transfer
         per_mcs = ring if obs_on else cnts

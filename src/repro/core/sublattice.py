@@ -31,8 +31,8 @@ from .rng import ProposalBatch
 from .rules import apply_pair
 
 
-def tile_update(tile: jax.Array, props: ProposalBatch, t_eps: float,
-                t_eps_mu: float, dom: jax.Array) -> jax.Array:
+def tile_update(tile: jax.Array, props: ProposalBatch, t_eps: jax.Array,
+                t_eps_mu: jax.Array, dom: jax.Array) -> jax.Array:
     """Sequentially apply K interior proposals to one (th, tw) tile.
 
     ``props.cell`` indexes the (th-2)x(tw-2) interior window; the chosen
@@ -81,15 +81,17 @@ def from_tiles(tiles: jax.Array, h: int, w: int) -> jax.Array:
                  .reshape(h, w))
 
 
-@partial(jax.jit, static_argnames=("tile_shape", "t_eps", "t_eps_mu",
-                                   "roll_back"))
+@partial(jax.jit, static_argnames=("tile_shape", "roll_back"))
 def run_round(grid: jax.Array, props: ProposalBatch, shift: jax.Array,
-              tile_shape: Tuple[int, int], t_eps: float, t_eps_mu: float,
-              dom: jax.Array, roll_back: bool = True) -> jax.Array:
+              tile_shape: Tuple[int, int], t_eps: jax.Array,
+              t_eps_mu: jax.Array, dom: jax.Array,
+              roll_back: bool = True) -> jax.Array:
     """One shifted-window round over the whole lattice (pure-jnp engine).
 
-    ``props`` arrays have shape (T, K). Requires periodic boundaries (the
-    roll assumes a torus); reflect boundaries use E1/E2.
+    ``props`` arrays have shape (T, K). The physics point (``t_eps``,
+    ``t_eps_mu``, ``dom``) is traced, so one compiled round serves every
+    point of a shape. Requires periodic boundaries (the roll assumes a
+    torus); reflect boundaries use E1/E2.
     """
     h, w = grid.shape
     th, tw = tile_shape

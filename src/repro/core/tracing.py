@@ -20,6 +20,12 @@ costs next to nothing. It is also timed with ``time.perf_counter`` into
 the chunk's record, profiler or not. Every blocking read of a chunk's
 outputs goes through :meth:`Run.read`, which counts it in ``syncs``.
 
+Two counters of the whole call sit beside them in ``Run.totals``:
+``traces``, the times JAX traced the call's chunk program (the driver
+dispatches it through :meth:`Run.counted`), and ``points``, the physics
+points of the call's batch (1 for ``simulate``; P for a ``run_trials``
+sweep over P scenarios).
+
 The record of the last driver call made in the process is
 :func:`last_run`: one :class:`Chunk` per consumed chunk (the last
 ``MAX_CHUNKS`` of them) and running totals over all of them. The drivers'
@@ -31,8 +37,9 @@ import contextlib
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Deque, Dict, Iterator, Optional
+from typing import Callable, Deque, Dict, Iterator, Optional
 
+import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
@@ -75,8 +82,20 @@ class Run:
         self.chunks: Deque[Chunk] = deque(maxlen=MAX_CHUNKS)
         self.first: Optional[Chunk] = None   # kept past the deque's bound
         self.totals: Dict[str, float] = {
-            **dict.fromkeys(SPANS, 0.0), "syncs": 0, "chunks": 0, "mcs": 0}
+            **dict.fromkeys(SPANS, 0.0), "syncs": 0, "chunks": 0, "mcs": 0,
+            "traces": 0, "points": 1}
         self.current = Chunk(0, self.start_s)
+
+    def counted(self, chunk: Callable) -> Callable:
+        """The jitted chunk program ``chunk``, called as ``chunk(*args,
+        n_mcs=n, **operands)``, jitted once more around a Python body
+        that counts each trace in ``totals["traces"]``: the body runs
+        only when JAX traces the program, never when it dispatches a
+        compiled one."""
+        def body(*args, n_mcs: int, **operands):
+            self.totals["traces"] += 1
+            return chunk(*args, n_mcs=n_mcs, **operands)
+        return jax.jit(body, static_argnames=("n_mcs",))
 
     @contextlib.contextmanager
     def span(self, name: str) -> Iterator[None]:

@@ -44,7 +44,7 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Callable, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +52,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from . import dominance as dom_mod
 from . import engines, lattice, metrics, tracing
 from . import observables as obs_mod
 from .params import EscgParams
@@ -246,6 +245,12 @@ def build_trial_chunk(p: EscgParams, dom: jax.Array,
     ``alive`` is the only per-MCS output and is what the host streams
     statistics from.
 
+    An engine with ``EngineCaps.physics_operand`` also takes the chunk's
+    keyword operand ``point`` (``engines.Physics``, each field with a
+    leading trial axis): each trial's own physics, as ``run_trials``
+    stacks a sweep. Without it every trial runs the point the engine was
+    built with.
+
     Two shapes of engine fit this contract (DESIGN.md §4/§6):
 
     * vmappable engines: ``one_mcs(grid, key)`` is vmapped over the
@@ -410,12 +415,14 @@ def build_trial_chunk(p: EscgParams, dom: jax.Array,
         return chunk
 
     @partial(jax.jit, static_argnames=("n_mcs",))
-    def chunk(grids, keys, n_mcs: int):
-        def one(grid, key):
+    def chunk(grids, keys, n_mcs: int, point=None):
+        def one(grid, key, point):
+            step = engines.stepper(one_mcs, point)
+
             def body(carry, _):
                 g, k, kept, att = carry
                 k, k1 = jax.random.split(k)
-                g, k2, a2 = one_mcs(g, k1)
+                g, k2, a2 = step(g, k1)
                 cnt = metrics.counts(g, s)
                 row = (pipe.row(g, cnt) if pipe is not None
                        else jnp.int32(0))
@@ -426,7 +433,7 @@ def build_trial_chunk(p: EscgParams, dom: jax.Array,
             if pipe is not None:
                 out += (rows,)
             return out
-        out = jax.vmap(one)(grids, keys)
+        out = jax.vmap(one)(grids, keys, point)   # a sweep's point per trial
         if pipe is not None:
             out = out[:6] + (jnp.moveaxis(out[6], 0, 1),)
         return out
@@ -437,8 +444,9 @@ def build_trial_chunk(p: EscgParams, dom: jax.Array,
 def build_trial_obs_chunk(p: EscgParams, dom: jax.Array,
                           built: Optional[engines.BuiltEngine] = None):
     """Observable-pipeline trial chunk (DESIGN.md §11): ``chunk(grids,
-    keys, ring, pos, n_mcs<static>) -> (grids, keys, ring, pos,
-    final_counts, alive, kept, attempts)``; returns ``(chunk, pipeline)``.
+    keys, ring, pos, n_mcs<static>, **operands) -> (grids, keys, ring,
+    pos, final_counts, alive, kept, attempts)``, the operands (``point``)
+    as in :func:`build_trial_chunk`; returns ``(chunk, pipeline)``.
 
     The banked per-MCS rows are copied into the device-resident ring
     buffer (shape ``(capacity, n_pad, obs_width)``) inside the jitted
@@ -452,9 +460,9 @@ def build_trial_obs_chunk(p: EscgParams, dom: jax.Array,
     inner = build_trial_chunk(p, dom, built=built, pipe=pipe)
 
     @partial(jax.jit, static_argnames=("n_mcs",))
-    def chunk(grids, keys, ring, pos, n_mcs: int):
-        grids, keys, cnts, alive, kept, att, rows = inner(grids, keys,
-                                                          n_mcs)
+    def chunk(grids, keys, ring, pos, n_mcs: int, **operands):
+        grids, keys, cnts, alive, kept, att, rows = inner(
+            grids, keys, n_mcs=n_mcs, **operands)
         ring, pos = obs_mod.ring_push_many(ring, pos, rows)
         return grids, keys, ring, pos, cnts, alive, kept, att
 
@@ -470,7 +478,8 @@ def _first_true_mcs(mask: np.ndarray, offset: int) -> np.ndarray:
     return np.where(hit, first, -1)
 
 
-def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
+def run_trials(params: Union[EscgParams, Sequence[EscgParams]],
+               dom: Optional[np.ndarray] = None,
                n_trials: int = 1, key: Optional[jax.Array] = None,
                n_mcs: Optional[int] = None,
                trial_devices: Optional[int] = None,
@@ -480,7 +489,7 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
                async_stats: bool = True,
                engine_config=None, run_config=None, *,
                engine=None, run=None,
-               ) -> TrialResult:
+               ) -> Union[TrialResult, List[TrialResult]]:
     """Run ``n_trials`` IID simulations, vmapped and device-sharded.
 
     Scenario-first signature (DESIGN.md §10): ``run_trials(scenario,
@@ -492,6 +501,21 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
     flat form ``run_trials(params, dom, ...)`` still works behind a
     ``DeprecationWarning`` (``engine_config=``/``run_config=`` are the
     equally-deprecated spellings of ``engine=``/``run=``).
+
+    A sweep (DESIGN.md §4): given a list of scenarios in place of one,
+    the call runs ``n_trials`` of each as ONE batch,
+    point-major, with one chunk program, and returns a list with one
+    ``TrialResult`` per scenario, in order. The scenarios must share
+    every field that sets the batch's shape (``scenarios.resolve_points``
+    raises a ``ValueError`` naming the field that differs); each trial
+    gets its point's dominance matrix and action thresholds as operands
+    of the engine, so the engine must take them
+    (``EngineCaps.physics_operand``: sublattice, reference, batched).
+    Trial i of every point uses the key ``fold_in(key, i)``, so point p's
+    result is bit-identical to ``run_trials(scenario_p, n_trials=...)``
+    with the same key: common random numbers across the points. Hooks get
+    the alive counts of all P × n_trials trials, point-major, and the
+    stasis early exit waits for every trial of every point.
 
     The batch is padded to a multiple of the pod width (``trial_devices``,
     default: all local devices), placed with the trial axis sharded across
@@ -537,15 +561,18 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
 
     Each chunk boundary runs in the ``escg.*`` spans of ``core/tracing.py``
     and is kept, with its count of blocking reads, in
-    ``tracing.last_run()``.
+    ``tracing.last_run()``, beside the call's ``traces`` of the chunk
+    program and its ``points``.
     """
-    from .scenarios import resolve_config  # lazy: scenarios imports core
+    from .scenarios import resolve_points  # lazy: scenarios imports core
     from .simulation import _resolve_call_form  # lazy: avoid cycle
     loop = tracing.begin("run_trials")
     engine_config, run_config = _resolve_call_form(
         "run_trials", params, engine_config, run_config, engine, run)
-    params, dom = resolve_config(params, dom, engine_config, run_config)
-    p = params.validate()
+    sweep = isinstance(params, (list, tuple))
+    p, points = resolve_points(params if sweep else [params], dom,
+                               engine_config, run_config)
+    n_points = loop.totals["points"] = len(points)
     spec = engines.get_engine(p.engine)
     composed = spec.caps.pod_composable
     if composed:
@@ -564,11 +591,15 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
             and (trial_devices or 1) > 1:
         raise ValueError(f"engine {p.engine!r} does not support trial-axis "
                          "sharding; use trial_devices=1")
+    if n_points > 1 and not spec.caps.physics_operand:
+        raise ValueError(
+            f"engine {p.engine!r} compiles its rates into its program "
+            "(EngineCaps.physics_operand is False), so a batch holds one "
+            f"physics point, not {n_points}; sweep on "
+            f"{_operand_engines()} or run one point per call")
     if n_trials < 1:
         raise ValueError("n_trials must be >= 1")
-    if dom is None:
-        dom = dom_mod.circulant(p.species)
-    dom_j = jnp.asarray(dom, jnp.float32)
+    dom_j = points[0].dom
     if key is None:
         key = jax.random.PRNGKey(p.seed)
     n_mcs = int(n_mcs if n_mcs is not None else p.mcs)
@@ -603,19 +634,36 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
                     else pod_sharding(1))
         n_dev = sharding.mesh.devices.size
         n_pad = pad_trials(n_trials, n_dev)
-        grids, keys = trial_grids_and_keys(p, key, n_pad, sharding)
+        # point-major: every point's block of n_pad trials has the keys,
+        # and so the initial lattices, of a single-point call
+        trial_keys = fold_trial_keys(key, n_pad)
+        grids, keys = make_trial_init(p, sharding)(
+            jnp.concatenate([trial_keys] * n_points))
         pod_mesh = sharding.mesh
         if p.observables:
             chunk_fn, pipe = build_trial_obs_chunk(p, dom_j)
         else:
             chunk_fn = build_trial_chunk(p, dom_j)
+    chunk_fn = loop.counted(chunk_fn)
+    operands = {}
+    if n_points > 1:
+        # a sweep hands each trial its point's physics; one point is the
+        # engine's own, built into the program as a single call's is
+        operands["point"] = jax.device_put(jax.tree.map(
+            lambda *xs: jnp.repeat(jnp.stack(xs), n_pad, axis=0), *points),
+            sharding)
+    n_batch = n_points * n_pad
+
+    def per_point(a):
+        """(n_batch, ...) -> (n_points, n_trials, ...): padding dropped."""
+        return a.reshape((n_points, n_pad) + a.shape[1:])[:, :n_trials]
 
     obs_on = bool(p.observables)
     ring = pos = None
     rows_all = []
     if obs_on:
         cap = obs_mod.ring_capacity(p, max(1, chunk_len))
-        ring, pos = obs_mod.ring_init(cap, (n_pad, pipe.width))
+        ring, pos = obs_mod.ring_init(cap, (n_batch, pipe.width))
         # ring rows shard with the trial axis — flushes stay device-local
         # per pod group until the host copy
         ring = jax.device_put(
@@ -626,10 +674,10 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
     init_cnts = np.asarray(jax.jit(jax.vmap(
         lambda g: metrics.counts(g, s)))(grids))
     ext = np.where(init_cnts[:, 1:] > 0, -1, 0).astype(np.int64)
-    stasis = np.full(n_pad, -1, np.int64)
+    stasis = np.full(n_batch, -1, np.int64)
     surv = init_cnts[:, 1:] > 0
     final_cnts = init_cnts
-    kept_tot = att_tot = 0
+    kept_tot = att_tot = np.zeros(n_points, np.int64)
     done = 0
 
     # One chunk is kept in flight ahead of the host (async_stats): the
@@ -639,8 +687,9 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
     # never read, so statistics and mcs_completed are schedule-independent.
     def dispatch(grids, keys, ring, pos, m):
         if obs_on:
-            return chunk_fn(grids, keys, ring, pos, m)
-        g, k, cnts, alive, kept, att = chunk_fn(grids, keys, m)
+            return chunk_fn(grids, keys, ring, pos, n_mcs=m, **operands)
+        g, k, cnts, alive, kept, att = chunk_fn(grids, keys, n_mcs=m,
+                                                **operands)
         return g, k, None, None, cnts, alive, kept, att
 
     m = min(chunk_len, n_mcs)
@@ -661,7 +710,7 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
         with loop.span(tracing.WAIT):
             jax.block_until_ready((alive, cnts, kept, att))
         with loop.span(tracing.READBACK):
-            alive_h = loop.read(alive)                # (n_pad, m, S) bool
+            alive_h = loop.read(alive)                # (n_batch, m, S) bool
             ring_h = loop.read(ring) if obs_on else None
             final_cnts = loop.read(cnts)
             kept_h, att_h = loop.read(kept), loop.read(att)
@@ -670,10 +719,10 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
                 # one flush per CONSUMED chunk (the in-flight speculative
                 # chunk past an early-exit is dropped unflushed)
                 rows_all.append(obs_mod.ring_flush(ring_h, done, done + m))
-            kept_tot += int(kept_h[:n_trials].sum())
-            att_tot += int(att_h[:n_trials].sum())
+            kept_tot = kept_tot + per_point(kept_h).sum(axis=1)
+            att_tot = att_tot + per_point(att_h).sum(axis=1)
 
-            first_dead = _first_true_mcs(~alive_h, done)     # (n_pad, S)
+            first_dead = _first_true_mcs(~alive_h, done)     # (n_batch, S)
             ext = np.where((ext < 0) & (first_dead > 0), first_dead, ext)
             first_stasis = _first_true_mcs(alive_h.sum(axis=2) <= 1, done)
             stasis = np.where((stasis < 0) & (first_stasis > 0),
@@ -682,28 +731,34 @@ def run_trials(params: EscgParams, dom: Optional[np.ndarray] = None,
             done += m
         with loop.span(tracing.HOOKS):
             for hook in hooks:
-                hook(done, surv[:n_trials].sum(axis=1))
+                hook(done, per_point(surv).sum(axis=2).reshape(-1))
         loop.close(done)
-        if stop_on_stasis and (stasis[:n_trials] >= 0).all():
+        if stop_on_stasis and (per_point(stasis) >= 0).all():
             break
         if m_next and out is None:                   # async_stats=False
             with loop.span(tracing.DISPATCH):
                 out = dispatch(grids, keys, ring, pos, m_next)
         m = m_next
 
-    observables = {}
+    streams = [{} for _ in range(n_points)]
     if obs_on and rows_all:
-        rows = np.concatenate(rows_all, axis=0)      # (T, n_pad, W)
-        observables = pipe.split(np.moveaxis(rows, 0, 1)[:n_trials])
+        rows = np.concatenate(rows_all, axis=0)      # (T, n_batch, W)
+        streams = [pipe.split(r) for r in per_point(np.moveaxis(rows, 0, 1))]
 
-    return TrialResult(
-        survival=surv[:n_trials].astype(bool),
-        densities=final_cnts[:n_trials] / p.n_cells,
-        stasis_mcs=stasis[:n_trials],
-        extinction_mcs=ext[:n_trials],
+    results = [TrialResult(
+        survival=per_point(surv)[q].astype(bool),
+        densities=per_point(final_cnts)[q] / p.n_cells,
+        stasis_mcs=per_point(stasis)[q],
+        extinction_mcs=per_point(ext)[q],
         mcs_completed=done,
-        kept_fraction=(kept_tot / att_tot) if att_tot else 1.0,
+        kept_fraction=float(kept_tot[q] / att_tot[q]) if att_tot[q] else 1.0,
         n_trials=n_trials,
         n_devices=n_dev,
-        observables=observables,
-    )
+        observables=streams[q],
+    ) for q in range(n_points)]
+    return results if sweep else results[0]
+
+
+def _operand_engines() -> str:
+    return ", ".join(s.name for s in engines.engine_specs()
+                     if s.caps.physics_operand)

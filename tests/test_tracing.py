@@ -107,6 +107,29 @@ def test_a_call_with_no_mcs_keeps_no_chunk():
     assert run.totals["chunks"] == 0
 
 
+@pytest.mark.parametrize("driver", ["simulate", "run_trials"])
+def test_a_call_traces_its_chunk_program_once(driver):
+    run = _run(driver, False, mcs=6, chunk=2)
+    assert run.totals["traces"] == 1 and run.totals["points"] == 1
+
+
+def test_a_shorter_last_chunk_traces_the_program_again():
+    run = _run("run_trials", False, mcs=7, chunk=2)
+    assert run.totals["traces"] == 2
+
+
+@pytest.mark.parametrize("n_points", [2, 3])
+def test_a_sweep_traces_one_program_for_all_its_points(n_points):
+    scs = [make_scenario("park3", mobility=m)
+           for m in (3e-5, 3e-4, 3e-3)[:n_points]]
+    run_trials(scs, n_trials=3, engine=ENGINE, stop_on_stasis=False,
+               run=RunConfig(height=12, length=12, mcs=4, chunk_mcs=2,
+                             seed=3, observables=()))
+    run = tracing.last_run()
+    assert run.totals["traces"] == 1 and run.totals["points"] == n_points
+    assert run.totals["chunks"] == 2
+
+
 def _host_span_events(log_dir):
     from jax.profiler import ProfileData
 

@@ -3,17 +3,24 @@
 Fast tests run on the single real CPU device; the device-layout
 bit-identity acceptance test spawns a subprocess with fake CPU devices
 (slow, nightly CI)."""
+import functools
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import EscgParams, dominance as dm
+from repro.core import (EngineConfig, EscgParams, RunConfig,
+                        dominance as dm, make_scenario)
 from repro.core.trials import (TrialResult, build_trial_chunk, pad_trials,
                                pod_sharding, run_trials,
                                trial_grids_and_keys)
 
 pytestmark = pytest.mark.composed   # re-run by the CI 8-fake-device job
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def small_params(**kw):
@@ -243,6 +250,145 @@ def test_legacy_wrapper_returns_survival_mask():
     surv = legacy(small_params(), dm.RPS(), 5, n_mcs=10)
     assert isinstance(surv, np.ndarray)
     assert surv.shape == (5, 3) and surv.dtype == bool
+
+
+# ------------------------------- sweeps ------------------------------------ #
+# A sweep runs P scenarios of one shape as one batch, each trial with its
+# point's dominance matrix and thresholds as engine operands; trial i of
+# every point keeps the key of a single-point call.
+
+SWEEP_RUN = RunConfig(height=24, length=24, mcs=3, chunk_mcs=1,
+                      seed=2**31 + 11)
+SWEEP_TILE = (8, 8)
+ALPHA_BETA = [(0.15, 0.75), (0.6, 0.0), (0.6, 0.5)]
+MOBILITIES = [0.0, 3e-4, 3e-3]
+
+
+POINTS = {
+    "alpha_beta": lambda: [make_scenario("probabilistic", alpha=a, beta=b)
+                           for a, b in ALPHA_BETA],
+    "mobility": lambda: [make_scenario("park3", mobility=m)
+                         for m in MOBILITIES],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _streamed(points, engine, q=None, n=4):
+    """run_trials over the sweep ``points`` (point ``q`` alone, if given)
+    with the alive counts each chunk streams to the hook, stacked on a
+    last axis. Kept for the module: each call compiles a program."""
+    scenarios = POINTS[points]()
+    seen = []
+    res = run_trials(scenarios if q is None else scenarios[q], n_trials=n,
+                     engine=EngineConfig(engine=engine, tile=SWEEP_TILE),
+                     run=SWEEP_RUN, stop_on_stasis=False,
+                     hooks=[lambda done, alive: seen.append(np.array(alive))])
+    return res, np.stack(seen, axis=-1)
+
+
+def _assert_same_trials(a, b):
+    for f in ("survival", "densities", "stasis_mcs", "extinction_mcs"):
+        np.testing.assert_array_equal(getattr(a, f), getattr(b, f))
+    assert (a.mcs_completed, a.kept_fraction, a.n_trials) == \
+        (b.mcs_completed, b.kept_fraction, b.n_trials)
+    assert a.observables.keys() == b.observables.keys()
+    for k in a.observables:
+        np.testing.assert_array_equal(a.observables[k], b.observables[k])
+
+
+@pytest.mark.parametrize("engine,points", [
+    ("sublattice", "alpha_beta"), ("reference", "alpha_beta"),
+    ("batched", "alpha_beta"), ("sublattice", "mobility")])
+def test_sweep_equals_single_point_calls(engine, points):
+    """One batch of 3 points x 4 trials is three single-point calls, bit
+    for bit: counts, alive streams, stasis, extinction and survival. The
+    (alpha, beta) sweep moves the dominance matrix; the park3 sweep over
+    mobility moves the threshold operands, and streams observables."""
+    sweep, alive = _streamed(points, engine)
+    assert isinstance(sweep, list) and len(sweep) == 3
+    alive = alive.reshape(3, 4, -1)
+    for q in range(3):
+        single, alive_q = _streamed(points, engine, q)
+        assert isinstance(single, TrialResult)
+        _assert_same_trials(sweep[q], single)
+        np.testing.assert_array_equal(alive[q], alive_q)
+    # each trial played its own point: the same lattices part ways
+    assert any(not np.array_equal(sweep[0].densities, r.densities)
+               for r in sweep[1:])
+
+
+def _reference_config(sc):
+    """A scenario as the plain reference's configuration: its physics
+    stated in full, dominance edges included."""
+    d = sc.dominance()
+    return {"species": sc.species, "neighbourhood": sc.neighbourhood,
+            "height": SWEEP_RUN.height, "length": SWEEP_RUN.length,
+            "empty": sc.empty, "tile": list(SWEEP_TILE),
+            "mobility": sc.mobility, "epsilon": sc.epsilon, "mu": sc.mu,
+            "sigma": sc.sigma,
+            "dominance": [[int(w), int(l), float(d[w, l])]
+                          for w, l in zip(*np.nonzero(d))]}
+
+
+@pytest.mark.parametrize("points", ["alpha_beta"])
+def test_sweep_points_follow_the_plain_reference(points):
+    """Each point's trials equal the benchmark's plain reference of the
+    sublattice engine (bench/references), which shares no code with the
+    program, followed under that point's own physics."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from bench import references
+
+    sweep, _ = _streamed(points, "sublattice")
+    n_cells = SWEEP_RUN.height * SWEEP_RUN.length
+    for q, sc in enumerate(POINTS[points]()):
+        counts = references.trials(_reference_config(sc), "sublattice",
+                                   SWEEP_RUN.seed, np.arange(4),
+                                   SWEEP_RUN.mcs)
+        np.testing.assert_array_equal(
+            np.rint(sweep[q].densities * n_cells).astype(np.int64),
+            counts[:, -1])
+        np.testing.assert_array_equal(sweep[q].survival,
+                                      counts[:, -1, 1:] > 0)
+
+
+@pytest.mark.parametrize("other,field", [
+    (dict(empty=0.1), "empty"),
+    (dict(neighbourhood=8), "neighbourhood"),
+    (dict(boundary="reflect"), "flux"),
+])
+def test_sweep_points_must_share_their_shape(other, field):
+    scs = [make_scenario("park3"), make_scenario("park3", **other)]
+    with pytest.raises(ValueError, match=f"differ in '{field}'"):
+        run_trials(scs, n_trials=2, engine=EngineConfig(engine="batched"),
+                   run=SWEEP_RUN)
+
+
+def test_sweep_points_must_share_their_species():
+    scs = [make_scenario("park3"), make_scenario("nspecies5")]
+    with pytest.raises(ValueError, match="differ in 'species'"):
+        run_trials(scs, n_trials=2, engine=EngineConfig(engine="batched"),
+                   run=SWEEP_RUN)
+
+
+@pytest.mark.parametrize("engine", ["pallas", "pallas_fused"])
+def test_engine_with_compiled_rates_refuses_two_points(engine):
+    with pytest.raises(ValueError, match="physics_operand"):
+        run_trials(POINTS["mobility"]()[:2], n_trials=2,
+                   engine=EngineConfig(engine=engine, tile=SWEEP_TILE),
+                   run=SWEEP_RUN)
+
+
+def test_park_survival_sweep_equals_its_points():
+    from repro.core import park
+
+    key = jax.random.PRNGKey(5)
+    kw = dict(L=12, n_trials=3, mcs=4, key=key)
+    swept = park.survival_probabilities_sweep(ALPHA_BETA[:2], **kw)
+    for (a, b), (ps, hist) in zip(ALPHA_BETA[:2], swept):
+        ps1, hist1 = park.survival_probabilities(a, b, **kw)
+        np.testing.assert_array_equal(ps, ps1)
+        np.testing.assert_array_equal(hist, hist1)
 
 
 # ------------------------------ multi-device ------------------------------- #
